@@ -25,7 +25,7 @@ import numpy as np
 from repro.core import (
     PAPER_WORKLOADS, build_plan, census_batagelj_mrvar, census_dict,
     paper_workload, triad_census)
-from repro.core.generators import measured_exponent
+from repro.core.generators import measured_exponent, monitor_stream
 
 #: scaled-down workload sizes (nodes, avg outdegree) — shaped like the
 #: paper's patents (sparse, steep tail) / orkut (dense social) / webgraph
@@ -301,7 +301,7 @@ def device_emission(rows: list):
     # warmup — the row the device-emission path must improve
     rng = np.random.default_rng(0)
     window = 4000
-    src, dst, n = _monitor_stream(rng, 80, 3000, 800, 2 * window)
+    src, dst, n = monitor_stream(rng, 80, 3000, 800, 2 * window)
     from repro.core import from_edges
     g = from_edges(src[:window], dst[:window], n=n)
     # reciprocal delta: arcs of the NEXT window absent from g (so add
@@ -1018,37 +1018,6 @@ def twod_smoke(rows: list):
             f"{p2.stats.entry_replication:.2f}"))
 
 
-def _monitor_stream(rng, n_servers, n_peers, backbone_arcs, length,
-                    backbone_every=2, eph_every=None):
-    """Monitoring workload: a persistent service backbone (a fixed server
-    mesh cycled through the stream, so it sits in every window and never
-    churns) interleaved with ephemeral peer-to-peer flows that churn
-    completely between windows — the regime where incremental window
-    updates pay (arc deltas touch few rows).  ``backbone_every=k`` makes
-    every k-th stream slot a backbone edge (fraction 1/k); ``eph_every=k``
-    inverts the cadence — every k-th slot is EPHEMERAL and the rest are
-    backbone (fraction (k-1)/k), the backbone-dominated regime where the
-    pair space is large but the per-slide delta stays small."""
-    n = n_servers + n_peers
-    bs = rng.integers(0, n_servers, backbone_arcs)
-    bd = (bs + 1 + rng.integers(0, n_servers - 1, backbone_arcs)) \
-        % n_servers
-    src = np.empty(length, np.int64)
-    dst = np.empty(length, np.int64)
-    slots = np.arange(length)
-    if eph_every is not None:
-        bb = slots % eph_every != 0
-        idx = (np.cumsum(bb) - 1)[bb] % backbone_arcs
-    else:
-        bb = slots % backbone_every == 0
-        idx = (slots[bb] // backbone_every) % backbone_arcs
-    src[bb], dst[bb] = bs[idx], bd[idx]
-    n_peer_slots = int((~bb).sum())
-    src[~bb] = n_servers + rng.integers(0, n_peers, n_peer_slots)
-    dst[~bb] = n_servers + rng.integers(0, n_peers, n_peer_slots)
-    return src, dst, n
-
-
 def _run_monitor(src, dst, n, window, stride, incremental,
                  backend="jnp", max_items=4096, index=True):
     from repro.core import TriadMonitor
@@ -1068,7 +1037,7 @@ def temporal_windows(rows: list):
     the items processed plus the affected-pair fraction per window."""
     rng = np.random.default_rng(0)
     window = 4000
-    src, dst, n = _monitor_stream(rng, 80, 3000, 800, 11 * window)
+    src, dst, n = monitor_stream(rng, 80, 3000, 800, 11 * window)
     # warm the shared jitted chunk step (same static args / chunk shape
     # for every monitor below) so neither timed mode absorbs the compile
     warm = 2 * window
@@ -1105,7 +1074,7 @@ def temporal_smoke(rows: list):
     >= 2x fewer census items, on the jnp and pallas-fused backends."""
     rng = np.random.default_rng(0)
     window = 1500
-    src, dst, n = _monitor_stream(rng, 40, 1500, 300, 5 * window)
+    src, dst, n = monitor_stream(rng, 40, 1500, 300, 5 * window)
     stride = window // 10
     for backend in ("jnp", "pallas-fused"):
         # warm the chunk step so the timed runs compare algorithms, not
@@ -1161,7 +1130,7 @@ def incr_host_smoke(rows: list):
     n_slides = {0.05: 8, 0.20: 4}
     length = window + int(max(f * s for f, s in n_slides.items())
                           * window)
-    src, dst, n = _monitor_stream(rng, 20000, 50000, 150000, length,
+    src, dst, n = monitor_stream(rng, 20000, 50000, 150000, length,
                                   eph_every=50)
     for frac, gates in ((0.05, (1.5, 1.3)), (0.20, None)):
         stride = int(window * frac)

@@ -116,6 +116,9 @@ def main() -> None:
                 flags + " --xla_force_host_platform_device_count=8"
             ).strip()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     rows: list = []
     from benchmarks import census_bench
     if args.fault_smoke:

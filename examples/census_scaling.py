@@ -19,6 +19,7 @@ from repro.core import (
     census_dict, default_mesh, pair_space, paper_workload,
     triad_census_distributed)
 from repro.analysis.report import streaming_section
+from repro.compile_cache import enable_compile_cache
 
 SIZES = {"patents": (30_000, 3.0), "orkut": (5_000, 40.0),
          "webgraph": (15_000, 15.0)}
@@ -72,6 +73,7 @@ def streaming_demo(mesh):
 
 
 def main():
+    enable_compile_cache()
     mesh = default_mesh()
     ndev = len(jax.devices())
     print(f"devices: {ndev}  (mesh {mesh.axis_names})\n")

@@ -117,8 +117,11 @@ def main():
             os.environ["XLA_FLAGS"] = (
                 flags + f" --xla_force_host_platform_device_count="
                 f"{args.mesh}").strip()
+    from repro.compile_cache import enable_compile_cache
     from repro.core import (
         Fault, FaultPlan, SECURITY_PATTERNS, TriadMonitor, default_mesh)
+
+    enable_compile_cache()
 
     mesh = default_mesh(args.mesh) if args.mesh is not None else None
     rng = np.random.default_rng(0)

@@ -5,12 +5,14 @@
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     build_plan, census_bruteforce, census_dict, from_edges,
     scale_free_digraph, triad_census)
 
 
 def main():
+    enable_compile_cache()
     # a small scale-free graph (orkut-like mutual density)
     g = scale_free_digraph(n=2_000, avg_degree=8, exponent=2.1,
                            mutual_p=0.5, seed=42)

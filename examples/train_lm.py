@@ -15,6 +15,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.data.pipeline import DataConfig, TokenPipeline
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--inject-failure", action="store_true", default=True)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # a genuinely trainable-on-CPU config of the selected family
     cfg = dataclasses.replace(

@@ -8,7 +8,8 @@ Public API::
     census = triad_census_distributed(plan, mesh)   # sharded + psum
 
     # out-of-core: never materialize the O(W) plan — stream bounded chunks
-    engine = CensusEngine(mesh, backend="pallas-fused")
+    # (backend="jnp", the default, is the one that runs on a TPU)
+    engine = CensusEngine(mesh)
     census = engine.run(g, max_items=10_000_000)
     engine.stats.summary()                      # chunks, peak plan bytes
 
@@ -20,7 +21,7 @@ Public API::
     # partitioned: shard the GRAPH, not just the items — each device
     # holds only its pair shard's local subgraph (O(E_shard + halo))
     part = partition_graph(g, num_shards=8); print(shard_report(part))
-    engine = CensusEngine(mesh, backend="pallas-fused", partition=True)
+    engine = CensusEngine(mesh, partition=True)
     census = engine.run(g)            # bit-identical, private shards
     session = engine.session(g)       # deltas dispatch owning shards only
 
@@ -33,7 +34,7 @@ Public API::
     # witness range across V vertex slices — the adjacency halo shards
     # too, not just the pairs
     part = partition_graph_2d(g, mesh_shape=(4, 2))
-    engine = CensusEngine(mesh, backend="pallas-fused", partition_2d=(4, 2))
+    engine = CensusEngine(mesh, partition_2d=(4, 2))
     census = engine.run(g)            # still bit-identical
 """
 
@@ -51,10 +52,11 @@ from repro.core.faults import (
     Fault, FaultError, FaultInjector, FaultPlan, InjectedFault)
 from repro.core.planner import PlanOverflowError
 from repro.core.census import (
-    triad_census, assemble_census, census_partials_desc_batch)
+    triad_census, assemble_census, census_partials_desc_batch,
+    check_backend)
 from repro.core.engine import (
     CensusEngine, EMIT_MODES, SCHEDULES, EngineSession, EngineStats,
-    PartitionedEngineSession, PartitionedEngineSession2D)
+    PartitionedEngineSession, PartitionedEngineSession2D, StepCompileError)
 from repro.core.incremental import (
     affected_pair_ids, subset_contribution, subset_descriptor_windows,
     verify_delta_closure)
@@ -71,7 +73,8 @@ from repro.core.census_ref import (
 from repro.core.tricode import (
     TRIAD_NAMES, TRICODE_TO_CLASS, FOLD_64_TO_16, NUM_CLASSES)
 from repro.core.generators import (
-    scale_free_digraph, paper_workload, erdos_renyi_digraph, PAPER_WORKLOADS)
+    scale_free_digraph, paper_workload, erdos_renyi_digraph, monitor_stream,
+    PAPER_WORKLOADS)
 from repro.core.temporal import (
     TriadMonitor, SECURITY_PATTERNS, SECURITY_PATTERN_INDICES)
 
@@ -88,7 +91,7 @@ __all__ = [
     "PlanOverflowError",
     "CensusEngine", "EMIT_MODES", "SCHEDULES", "EngineSession",
     "EngineStats", "PartitionedEngineSession",
-    "PartitionedEngineSession2D",
+    "PartitionedEngineSession2D", "StepCompileError",
     "affected_pair_ids", "subset_contribution",
     "subset_descriptor_windows", "verify_delta_closure",
     "IndexCorruptionError", "PairSpaceIndex",
@@ -97,10 +100,12 @@ __all__ = [
     "partition_graph_2d", "replicated_graph_bytes", "vertex_slices",
     "shard_report",
     "triad_census", "assemble_census", "census_partials_desc_batch",
+    "check_backend",
     "triad_census_distributed", "triad_census_graph", "default_mesh",
     "census_bruteforce", "census_batagelj_mrvar", "census_dict",
     "TRIAD_NAMES", "TRICODE_TO_CLASS", "FOLD_64_TO_16", "NUM_CLASSES",
     "scale_free_digraph", "paper_workload", "erdos_renyi_digraph",
+    "monitor_stream",
     "PAPER_WORKLOADS", "TriadMonitor", "SECURITY_PATTERNS",
     "SECURITY_PATTERN_INDICES",
 ]

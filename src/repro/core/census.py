@@ -10,13 +10,15 @@ of the paper's privatized census vectors.
 
 Backends:
 
-* ``jnp``          — pure XLA; the oracle for everything below.
+* ``jnp``          — pure XLA; the oracle for everything below, and the
+                     path that runs on a TPU at real graph sizes.
 * ``pallas``       — classification in XLA, the 64-bin histogram hot loop
                      in the Pallas :mod:`repro.kernels.tricode_hist` kernel.
 * ``pallas-fused`` — the whole per-item pipeline (gather, binary search,
                      classification, histogram) in one Pallas kernel; the
                      per-item tricode array never materializes in HBM
-                     (:mod:`repro.kernels.census_fused`).
+                     (:mod:`repro.kernels.census_fused`).  Interpret mode
+                     only: the TPU compiler refuses it (:data:`TPU_REFUSED`).
 
 Returned per device/shard: ``hist64`` (connected-triad tricode histogram)
 and ``inter`` (2-bin count of N(u)∩N(v) elements split by pair mutuality),
@@ -50,6 +52,25 @@ from repro.core.planner import CensusPlan
 from repro.core.tricode import FOLD_64_TO_16
 
 BACKENDS = ("jnp", "pallas", "pallas-fused")
+
+#: backends the TPU kernel compiler (Mosaic) refuses, with its reason
+TPU_REFUSED = {
+    "pallas-fused": (
+        "Mosaic refuses the fused kernel's in-kernel 1-D vector gathers "
+        "(NotImplementedError: Only 2D gather is supported), and the "
+        "kernel pins the whole CSR and pair arrays in VMEM; use "
+        "backend='jnp'"),
+}
+
+
+def check_backend(backend: str, platform: str) -> None:
+    """Raise :class:`ValueError` for an unknown ``backend``, or for one
+    whose kernels cannot be built on ``platform``."""
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown backend {backend!r}; one of {BACKENDS}")
+    if platform == "tpu" and backend in TPU_REFUSED:
+        raise ValueError(f"backend {backend!r} cannot run on a TPU: "
+                         f"{TPU_REFUSED[backend]}")
 
 
 def segment_searchsorted(keys, lo, hi, q, iters: int):
@@ -359,7 +380,8 @@ def triad_census(plan: CensusPlan, backend: str = "jnp") -> np.ndarray:
     Thin wrapper over :class:`repro.core.engine.CensusEngine` (mesh-less,
     monolithic).  ``backend='pallas'`` routes the histogram hot loop
     through the Pallas kernel; ``backend='pallas-fused'`` runs the whole
-    per-item pipeline in one Pallas kernel (both interpret mode on CPU).
+    per-item pipeline in one Pallas kernel (interpret mode off TPU; refused
+    on TPU).
     """
     from repro.core.engine import CensusEngine
     return CensusEngine(mesh=None, backend=backend).run_plan(plan)

@@ -75,12 +75,12 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.census import (
-    BACKENDS, assemble_census, assemble_counts,
-    census_partials_desc_batch, desc_partials_fn, partials_fn)
+    assemble_census, assemble_counts, census_partials_desc_batch,
+    check_backend, desc_partials_fn, partials_fn)
 from repro.core.digraph import CompactDigraph, GraphDelta, apply_delta
 from repro.core.faults import FaultError, FaultPlan, poison_result
 from repro.core.incremental import (
@@ -170,13 +170,17 @@ _chunk_step_plain = functools.partial(
     jax.jit, static_argnames=_STATIC)(_chunk_step_impl)
 
 
+def _platform(mesh=None) -> str:
+    """The platform the work runs on: the mesh's device platform when
+    sharded, the default backend when single-device."""
+    return (mesh.devices.flat[0].platform if mesh is not None
+            else jax.default_backend())
+
+
 def _chunk_step(mesh=None):
-    """The per-chunk jitted step for the platform the work runs on —
-    the mesh's device platform when sharded, the default backend when
-    single-device."""
-    platform = (mesh.devices.flat[0].platform if mesh is not None
-                else jax.default_backend())
-    return _chunk_step_plain if platform == "cpu" else _chunk_step_donated
+    """The per-chunk jitted step for the platform the work runs on."""
+    return (_chunk_step_plain if _platform(mesh) == "cpu"
+            else _chunk_step_donated)
 
 
 def _desc_step_impl(indptr, packed, pair_u, pair_v, pair_code,
@@ -262,9 +266,7 @@ def _desc_megastep(mesh=None):
     ring buffers are donated on accelerators (each upload's HBM is
     reused by the next double-buffered batch), plain on CPU (no
     donation support)."""
-    platform = (mesh.devices.flat[0].platform if mesh is not None
-                else jax.default_backend())
-    return (_desc_megastep_plain if platform == "cpu"
+    return (_desc_megastep_plain if _platform(mesh) == "cpu"
             else _desc_megastep_donated)
 
 
@@ -341,10 +343,43 @@ _part_desc_step = functools.partial(
 
 
 def _jit_cache_size(step) -> int:
-    """Compile counter via jax's private ``_cache_size`` — if a jax
-    upgrade drops it, only the ``step_compiles`` stat degrades (to 0),
-    never the census itself."""
-    return getattr(step, "_cache_size", lambda: 0)()
+    """Compile counter of a jitted step (the ``step_compiles`` stat)."""
+    return step._cache_size()
+
+
+class StepCompileError(RuntimeError):
+    """A census step failed to trace, lower or compile.
+
+    The compiler refuses the same program on every attempt and every
+    device, so this is raised at once: never retried, failed over, or
+    carried forward as a degraded monitor window.  The message names the
+    step, the backend and the argument shapes; the compiler's own
+    exception is the ``__cause__``."""
+
+
+def _describe_args(args) -> str:
+    return ", ".join(f"{a.dtype}{list(a.shape)}" if hasattr(a, "shape")
+                     else repr(a) for a in args)
+
+
+def _launch(step, backend: str, *args):
+    """Call the jitted ``step`` on ``args``.  A failure is classified by
+    lowering and compiling the same call again: if that fails too, the
+    step cannot be built and :class:`StepCompileError` is raised; if it
+    succeeds, the failure was a device runtime error and propagates
+    unchanged to the caller's retry discipline.  The re-lowering runs
+    only on the failure path and reuses the compile the call made."""
+    try:
+        return step(*args)
+    except Exception as exc:
+        try:
+            step.lower(*args).compile()
+        except Exception:
+            raise StepCompileError(
+                f"{step.__name__} failed to compile for backend "
+                f"{backend!r} with arguments ({_describe_args(args)})"
+            ) from exc
+        raise
 
 
 #: bytes per packed work item (two int32 words)
@@ -712,9 +747,7 @@ class CensusEngine:
                  max_retries: int = 2, retry_backoff: float = 0.01,
                  watchdog_timeout: float | None = None,
                  faults: FaultPlan | None = None):
-        if backend not in BACKENDS:
-            raise ValueError(
-                f"unknown backend {backend!r}; one of {BACKENDS}")
+        check_backend(backend, _platform(mesh))
         if emit not in EMIT_MODES:
             raise ValueError(
                 f"unknown emit mode {emit!r}; one of {EMIT_MODES}")
@@ -838,7 +871,8 @@ class CensusEngine:
         rep, item_sh = self._shardings()
         step = _chunk_step(self.mesh)
         cache0 = _jit_cache_size(step)
-        hist64, inter = step(
+        hist64, inter = _launch(
+            step, self.backend,
             self._put(plan.indptr, rep), self._put(plan.packed, rep),
             self._put(plan.pair_u, rep), self._put(plan.pair_v, rep),
             self._put(plan.pair_code, rep),
@@ -1086,8 +1120,8 @@ class CensusEngine:
             # (dispatch is async; we only block when accumulating k-1)
             sp_dev = self._put(chunk.item_sp, item_sh)
             pv_dev = self._put(chunk.item_pv, item_sh)
-            fut = step(*graph_dev, sp_dev, pv_dev,
-                       self.mesh, space.search_iters, self.backend)
+            fut = _launch(step, self.backend, *graph_dev, sp_dev, pv_dev,
+                          self.mesh, space.search_iters, self.backend)
             if pending is not None:
                 hist_acc += np.asarray(pending[0], dtype=np.int64)
                 inter_acc += np.asarray(pending[1], dtype=np.int64)
@@ -1160,10 +1194,10 @@ class CensusEngine:
             base_mut += bm
             win = chunker.descriptors(k)
             words = self._put(win.device_words(), rep)
-            fut = _desc_step(*graph_dev, words, idx_dev,
-                             self.mesh, space.search_iters,
-                             chunker.desc_iters, self.backend,
-                             space.orient, space.prune_self)
+            fut = _launch(_desc_step, self.backend, *graph_dev, words,
+                          idx_dev, self.mesh, space.search_iters,
+                          chunker.desc_iters, self.backend,
+                          space.orient, space.prune_self)
             if pending is not None:
                 land(pending, k - 1)
             pending = fut
@@ -1285,9 +1319,10 @@ class CensusEngine:
 
             for k in range(sched.num_steps):
                 words = self._put(sched.step_words(k), dev_sh)
-                fut = step(*graph_dev, words, idx_dev, self.mesh,
-                           space.search_iters, sched.desc_iters,
-                           self.backend, space.orient, space.prune_self)
+                fut = _launch(step, self.backend, *graph_dev, words,
+                              idx_dev, self.mesh, space.search_iters,
+                              sched.desc_iters, self.backend,
+                              space.orient, space.prune_self)
                 if pending is not None:
                     land(pending, k - 1)
                 pending = fut
@@ -1301,11 +1336,10 @@ class CensusEngine:
                 chunk_items.append(int(sum(nums)))
                 if progress is not None:
                     progress(k, sched.num_steps, chunk_items[-1])
-                fut = step(graph_dev[0], graph_dev[1], graph_dev[2],
-                           graph_dev[3], graph_dev[4],
-                           self._put(item_sp, dev_sh),
-                           self._put(item_pv, dev_sh),
-                           self.mesh, space.search_iters, self.backend)
+                fut = _launch(step, self.backend, *graph_dev,
+                              self._put(item_sp, dev_sh),
+                              self._put(item_pv, dev_sh),
+                              self.mesh, space.search_iters, self.backend)
                 if pending is not None:
                     hist_acc += np.asarray(pending[0], dtype=np.int64)
                     inter_acc += np.asarray(pending[1], dtype=np.int64)
@@ -1545,17 +1579,18 @@ class CensusEngine:
                 buf_d = jax.device_put(buf, d)
                 if injector is not None:
                     injector.fire("dispatch", shard=s, device=d_id)
-                fut = step(*dev[s], buf_d, idx[d_id],
-                           space.search_iters, sched.desc_iters,
-                           self.backend, space.orient, space.prune_self)
+                fut = _launch(step, self.backend, *dev[s], buf_d,
+                              idx[d_id], space.search_iters,
+                              sched.desc_iters, self.backend,
+                              space.orient, space.prune_self)
             else:
                 _wid, sp, pv, _num = window
                 sp_d = jax.device_put(sp, d)
                 pv_d = jax.device_put(pv, d)
                 if injector is not None:
                     injector.fire("dispatch", shard=s, device=d_id)
-                fut = step(*dev[s], sp_d, pv_d, None,
-                           space.search_iters, self.backend)
+                fut = _launch(step, self.backend, *dev[s], sp_d, pv_d,
+                              None, space.search_iters, self.backend)
             poisoned = (injector.take_poison()
                         if injector is not None else False)
             return fut, poisoned
@@ -1564,12 +1599,15 @@ class CensusEngine:
             """Dispatch with the retry/failover discipline: transient
             failures back off and retry on the same device up to
             ``max_retries``; a dead device (persistent fault) or an
-            exhausted budget retires the device and re-routes."""
+            exhausted budget retires the device and re-routes.  A step
+            that cannot compile is raised at once."""
             while True:
                 d_id = home[s]
                 try:
                     fut, poisoned = do_dispatch(s, window)
                     return fut, poisoned, attempts
+                except StepCompileError:
+                    raise
                 except Exception as exc:
                     dead = ((injector is not None
                              and injector.device_is_dead(d_id))
@@ -2073,9 +2111,10 @@ class EngineSession:
                 pv_dev = self.engine._put(item_pv, self._item_sh)
                 if inj is not None:
                     inj.fire("dispatch", shard=0, device=0)
-                fut = self._step(*self._dev, sp_dev, pv_dev,
-                                 self.engine.mesh, self.search_iters,
-                                 self.engine.backend)
+                backend = self.engine.backend
+                fut = _launch(self._step, backend, *self._dev, sp_dev,
+                              pv_dev, self.engine.mesh, self.search_iters,
+                              backend)
                 poisoned = inj.take_poison() if inj is not None else False
                 return fut, poisoned
 
@@ -2122,10 +2161,11 @@ class EngineSession:
                 words = put(win.device_words(), self._rep)
                 if inj is not None:
                     inj.fire("dispatch", shard=0, device=0)
-                fut = _desc_step(*self._dev, words, self._idx,
-                                 self.engine.mesh, self.search_iters,
-                                 self.desc_iters, self.engine.backend,
-                                 self.orient, self.prune_self)
+                backend = self.engine.backend
+                fut = _launch(_desc_step, backend, *self._dev, words,
+                              self._idx, self.engine.mesh,
+                              self.search_iters, self.desc_iters, backend,
+                              self.orient, self.prune_self)
                 poisoned = inj.take_poison() if inj is not None else False
                 return fut, poisoned
 
@@ -2599,10 +2639,11 @@ class PartitionedEngineSession:
         words = jax.device_put(win.device_words(), self._devices[s])
         if inj is not None:
             inj.fire("dispatch", shard=s, device=s)
-        fut = _desc_step(*self._dev[s], words, self._idx[s], None,
-                         self.search_iters, self.desc_iters,
-                         self.engine.backend, self.orient,
-                         self.prune_self)
+        backend = self.engine.backend
+        fut = _launch(_desc_step, backend, *self._dev[s], words,
+                      self._idx[s], None, self.search_iters,
+                      self.desc_iters, backend, self.orient,
+                      self.prune_self)
         return fut, (inj.take_poison() if inj is not None else False)
 
     def _dispatch_items(self, s: int, item_pair, item_slot, item_side):
@@ -2619,8 +2660,9 @@ class PartitionedEngineSession:
         pv_dev = jax.device_put(item_pv, dev)
         if inj is not None:
             inj.fire("dispatch", shard=s, device=s)
-        fut = self._step(*self._dev[s], sp_dev, pv_dev,
-                         None, self.search_iters, self.engine.backend)
+        backend = self.engine.backend
+        fut = _launch(self._step, backend, *self._dev[s], sp_dev, pv_dev,
+                      None, self.search_iters, backend)
         return fut, (inj.take_poison() if inj is not None else False)
 
     def _shard_jobs(self, s: int, pair_ids=None):

@@ -4,7 +4,8 @@ The paper evaluates on US patents (outdeg power-law exponent 3.126), Orkut
 (2.127) and a .uk webgraph (1.516).  We re-synthesize statistically similar
 graphs at configurable scale: bounded-Zipf out-degree sequences with either
 uniform or preferential target attachment, plus a direction mix so all 16
-triad types occur.
+triad types occur.  :func:`monitor_stream` generates the edge stream of
+the paper's network-monitoring application.
 """
 
 from __future__ import annotations
@@ -74,6 +75,40 @@ def paper_workload(name: str, n: int, avg_degree: float,
     return scale_free_digraph(n=n, avg_degree=avg_degree,
                               exponent=cfg["exponent"],
                               mutual_p=cfg["mutual_p"], seed=seed)
+
+
+def monitor_stream(rng, n_servers, n_peers, backbone_arcs, length,
+                   backbone_every=2, eph_every=None):
+    """Monitoring workload: a persistent service backbone (a fixed server
+    mesh cycled through the stream, so it sits in every window and never
+    churns) interleaved with ephemeral peer-to-peer flows that churn
+    completely between windows — the regime where incremental window
+    updates pay (arc deltas touch few rows).  ``backbone_every=k`` makes
+    every k-th stream slot a backbone edge (fraction 1/k); ``eph_every=k``
+    inverts the cadence — every k-th slot is EPHEMERAL and the rest are
+    backbone (fraction (k-1)/k), the backbone-dominated regime where the
+    pair space is large but the per-slide delta stays small.
+
+    Returns ``(src, dst, n)``: ``length`` int64 stream arcs over ``n =
+    n_servers + n_peers`` vertices (servers first)."""
+    n = n_servers + n_peers
+    bs = rng.integers(0, n_servers, backbone_arcs)
+    bd = (bs + 1 + rng.integers(0, n_servers - 1, backbone_arcs)) \
+        % n_servers
+    src = np.empty(length, np.int64)
+    dst = np.empty(length, np.int64)
+    slots = np.arange(length)
+    if eph_every is not None:
+        bb = slots % eph_every != 0
+        idx = (np.cumsum(bb) - 1)[bb] % backbone_arcs
+    else:
+        bb = slots % backbone_every == 0
+        idx = (slots[bb] // backbone_every) % backbone_arcs
+    src[bb], dst[bb] = bs[idx], bd[idx]
+    n_peer_slots = int((~bb).sum())
+    src[~bb] = n_servers + rng.integers(0, n_peers, n_peer_slots)
+    dst[~bb] = n_servers + rng.integers(0, n_peers, n_peer_slots)
+    return src, dst, n
 
 
 def erdos_renyi_digraph(n: int, p: float, seed: int = 0) -> CompactDigraph:

@@ -299,7 +299,9 @@ class TriadMonitor:
         forward (the alarm baseline stays aligned with the stream), and
         the next window forces a full recompute to re-sync the resident
         session.  Only the very first window — with no census to carry —
-        re-raises."""
+        re-raises.  A step that cannot compile
+        (:class:`~repro.core.engine.StepCompileError`) is not a fault and
+        always propagates."""
         try:
             census = emit(win)
         except FaultError as exc:

@@ -16,8 +16,11 @@ census vectors, one level lower in the hierarchy.
 Graph-shaped inputs (indptr, packed CSR, pair arrays) ride along as
 whole-array blocks pinned across grid steps; the kernel therefore requires
 them to fit in VMEM (fine for per-shard subproblems — shard the graph via
-:mod:`repro.core.distributed` before they outgrow it).  Validated in
-interpret mode on CPU, per the project contract.
+:mod:`repro.core.distributed` before they outgrow it).  Runs in interpret
+mode only: the TPU compiler refuses the in-kernel 1-D vector gathers of
+:func:`repro.core.census.classify_items` ("Only 2D gather is
+supported"), so :class:`repro.core.engine.CensusEngine` refuses this
+backend on a TPU (:data:`repro.core.census.TPU_REFUSED`).
 """
 
 from __future__ import annotations
@@ -148,8 +151,8 @@ def _pad_1d_to_lanes(a: jax.Array, fill) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("search_iters", "interpret"))
 def census_fused_kernel(indptr, packed, pair_u, pair_v, pair_code,
-                        item_sp, item_pv, search_iters: int,
-                        interpret: bool = True):
+                        item_sp, item_pv, search_iters: int, *,
+                        interpret: bool):
     """Fused census partials: ``(hist64 (64,), inter (2,))`` int32.
 
     ``item_sp``/``item_pv`` are the planner's packed work-item words,
@@ -188,7 +191,7 @@ def census_fused_desc_kernel(indptr, packed, pair_u, pair_v, pair_code,
                              desc_pair, desc_cum, desc_within0, anchors,
                              num_valid, idx, search_iters: int,
                              desc_iters: int, orient: str,
-                             prune_self: bool, interpret: bool = True):
+                             prune_self: bool, *, interpret: bool):
     """Fused census partials from pair descriptors:
     ``(hist64 (64,), inter (3,))`` int32.
 

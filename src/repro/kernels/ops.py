@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (the kernels are written for TPU
-BlockSpec tiling but validated on CPU via the Pallas interpreter, per the
-project contract).
+The raw kernels take ``interpret`` as a required argument; these wrappers
+resolve ``interpret=None`` from the platform — compiled by Mosaic on a
+TPU, the Pallas interpreter everywhere else — so no caller on a TPU
+interprets by accident.
 """
 
 from __future__ import annotations

@@ -33,8 +33,8 @@ def _kernel(q_ref, k_ref, kc_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pair_codes_kernel(q: jax.Array, k: jax.Array, kc: jax.Array,
-                      interpret: bool = True) -> jax.Array:
+def pair_codes_kernel(q: jax.Array, k: jax.Array, kc: jax.Array, *,
+                      interpret: bool) -> jax.Array:
     """Per-query matched code, 0 if absent. All inputs (B, 128) int32.
 
     Key ids must be unique within a row (CSR rows are strictly sorted), so

@@ -2,13 +2,17 @@
 
 The paper's hot spot is the concurrent increment of the shared census
 vector, which it fixes with 64 hash-privatized copies.  On TPU we eliminate
-contention structurally: each grid step reduces an 8K-item VMEM block of
-tricodes into a 64-bin one-hot partial sum (a compare-broadcast + reduction,
-MXU/VPU-shaped), accumulated in a VMEM-resident output block revisited
-across the grid — i.e. privatization at the VMEM level, one final fold.
+contention structurally: each grid step compares an 8K-item ``(ROWS,
+LANES)`` VMEM tile of tricodes against each of the 64 classes and folds
+the matches down the sublanes into per-lane counts — row ``c`` of a
+``(64, LANES)`` VMEM-resident output block revisited across the grid
+holds class ``c``'s per-lane partial sums, i.e. privatization at the
+VMEM level, one final lane fold outside the kernel.  The tile keeps its
+native 2-D layout throughout (no reshape to a column, which Mosaic
+cannot lay out).
 
-Masked (padding / non-canonical) items carry tricode 64 and fall outside
-the one-hot range, contributing nothing.
+Masked (padding / non-canonical) items carry tricode 64 and match no
+class, contributing nothing.
 """
 
 from __future__ import annotations
@@ -25,25 +29,30 @@ LANES = 128
 BLOCK_ITEMS = ROWS * LANES
 
 
+#: histogram classes (the 64 tricodes); one output row each
+CLASSES = 64
+
+
 def _kernel(tri_ref, out_ref):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    tri = tri_ref[...].reshape(BLOCK_ITEMS, 1)
-    cls = jax.lax.broadcasted_iota(jnp.int32, (BLOCK_ITEMS, 64), 1)
-    onehot = (tri == cls).astype(jnp.int32)
-    counts = jnp.sum(onehot, axis=0)                     # (64,)
-    out_ref[0, :64] += counts
+    tri = tri_ref[...]                                   # (ROWS, LANES)
+    for c in range(CLASSES):
+        out_ref[c:c + 1, :] += jnp.sum(
+            (tri == c).astype(jnp.int32), axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def tricode_histogram_kernel(tricode_masked: jax.Array,
-                             interpret: bool = True) -> jax.Array:
+def tricode_histogram_kernel(tricode_masked: jax.Array, *,
+                             interpret: bool) -> jax.Array:
     """64-bin histogram of tricodes in [0, 64); values >= 64 are ignored.
 
     ``tricode_masked``: (W,) int32, padded by the wrapper so that
-    W % BLOCK_ITEMS == 0.
+    W % BLOCK_ITEMS == 0.  ``interpret`` runs the Pallas interpreter
+    instead of compiling for the TPU (:mod:`repro.kernels.ops` picks it
+    from the platform).
     """
     w = tricode_masked.shape[0]
     assert w % BLOCK_ITEMS == 0, w
@@ -53,8 +62,8 @@ def tricode_histogram_kernel(tricode_masked: jax.Array,
         _kernel,
         grid=(grid,),
         in_specs=[pl.BlockSpec((ROWS, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((8, LANES), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((8, LANES), jnp.int32),
+        out_specs=pl.BlockSpec((CLASSES, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((CLASSES, LANES), jnp.int32),
         interpret=interpret,
     )(tri2d)
-    return out[0, :64]
+    return jnp.sum(out, axis=1)

@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat  # noqa: F401  (installs AxisType/make_mesh shims)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -19,9 +19,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.compat import shard_map
 
 
 def pipeline_apply(stage_fn, mesh: Mesh, axis: str = "pod"):

@@ -1,12 +1,8 @@
 """Test harness setup.
 
-* Forces 8 virtual CPU devices (before the first jax import) so the
-  mesh/sharding/distributed suites exercise real multi-device code paths
-  on the single-core CPU host.
-* Imports :mod:`repro.compat`, which installs forward-compat aliases
-  (``jax.shard_map``, ``jax.sharding.AxisType``, ``make_mesh`` accepting
-  ``axis_types``) on older jax releases — the suites are written against
-  the modern API.
+Forces 8 virtual CPU devices (before the first jax import) so the
+mesh/sharding/distributed suites exercise real multi-device code paths
+on the single-core CPU host.
 """
 
 import os
@@ -17,5 +13,3 @@ if "jax" not in sys.modules:
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-
-import repro.compat  # noqa: E402,F401

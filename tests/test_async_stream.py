@@ -263,6 +263,7 @@ class TestNoCrossShardSync:
             calls.append(1)
             return real(*a, **k)
 
+        spy._cache_size = real._cache_size
         monkeypatch.setattr(engine_mod, "_part_desc_step", spy)
         g = pl_graph(n=40, seed=23)
         engine = CensusEngine(mesh=default_mesh(4), backend="jnp",

@@ -268,9 +268,13 @@ class TestDeviceEmitSession:
         c0 = session.census()
         calls = []
         real_step = engine_mod._desc_step
-        monkeypatch.setattr(
-            engine_mod, "_desc_step",
-            lambda *a, **k: calls.append(1) or real_step(*a, **k))
+
+        def spy(*a, **k):
+            calls.append(1)
+            return real_step(*a, **k)
+
+        spy._cache_size = real_step._cache_size
+        monkeypatch.setattr(engine_mod, "_desc_step", spy)
         got = session.update([0], [1])        # arc already present
         np.testing.assert_array_equal(got, c0)
         assert calls == []

@@ -357,6 +357,7 @@ class TestPartitionedSession:
             calls.append(list(args[0].devices())[0].id)
             return real_step(*args, **kw)
 
+        spy._cache_size = real_step._cache_size
         monkeypatch.setattr(engine_mod, "_desc_step", spy)
         got = session.update([32], [30])
         monkeypatch.setattr(engine_mod, "_desc_step", real_step)
@@ -374,9 +375,12 @@ class TestPartitionedSession:
                                partition=True).session(g)
         c0 = session.census()
         calls = []
-        monkeypatch.setattr(
-            engine_mod, "_desc_step",
-            lambda *a, **k: calls.append(1))
+
+        def spy(*a, **k):
+            calls.append(1)
+
+        spy._cache_size = engine_mod._desc_step._cache_size
+        monkeypatch.setattr(engine_mod, "_desc_step", spy)
         got = session.update([0], [1])        # arc already present
         np.testing.assert_array_equal(got, c0)
         assert calls == []

@@ -42,8 +42,8 @@ def _shed_compile_cache():
     """Drop compiled executables around this module.  The mesh-shape ×
     orient × emit × schedule sweeps below compile many distinct
     multi-device programs; stacked on the rest of the suite's cache in
-    one process, the XLA CPU backend can segfault in a later
-    ``backend_compile`` (jaxlib 0.4.x).  Clearing before and after keeps
+    one process, the per-process executable population grows without
+    bound.  Clearing before and after keeps
     the per-process executable population bounded — per-test "compiled
     at most once" assertions elsewhere are per-engine-session and
     unaffected."""
