@@ -73,6 +73,21 @@ def check_backend(backend: str, platform: str) -> None:
                          f"{TPU_REFUSED[backend]}")
 
 
+def _stage(name: str):
+    """Trace the decorated census stage under ``jax.named_scope(name)``:
+    every step that calls it carries the name in its operations'
+    metadata, so a profile attributes device time to ``expand``,
+    ``classify``, ``keep`` and ``reduce`` by name.  Op metadata only —
+    the compiled program and every count are unchanged."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def staged(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return staged
+    return wrap
+
+
 def segment_searchsorted(keys, lo, hi, q, iters: int):
     """First index i in [lo, hi) with keys[i] >= q, per element (batched).
 
@@ -90,6 +105,7 @@ def segment_searchsorted(keys, lo, hi, q, iters: int):
     return lo
 
 
+@_stage("classify")
 def classify_items(indptr, packed, pair_u, pair_v, pair_code,
                    item_pair, item_slot, item_side, item_valid,
                    search_iters: int):
@@ -132,6 +148,7 @@ def classify_items(indptr, packed, pair_u, pair_v, pair_code,
     return tricode, count_mask, inter_mask, c_uv == 3
 
 
+@_stage("expand")
 def expand_work_items(indptr, pair_u, pair_v, desc_pair, desc_cum,
                       desc_within0, anchors, num_valid, idx,
                       desc_iters: int):
@@ -176,6 +193,7 @@ def expand_work_items(indptr, pair_u, pair_v, desc_pair, desc_cum,
             jnp.where(valid, side, 0), valid)
 
 
+@_stage("keep")
 def prune_keep_mask(packed, pair_u, pair_v, pair_code,
                     item_pair, item_slot, item_side, item_valid,
                     orient: str, prune_self: bool):
@@ -199,6 +217,7 @@ def prune_keep_mask(packed, pair_u, pair_v, pair_code,
     return item_valid
 
 
+@_stage("reduce")
 def _partials_reduce(tricode, count_mask, inter_mask, is_mut,
                      histogram_fn=None, keep_mask=None):
     """Shared reduction tail: fold per-item classifications into the

@@ -65,6 +65,7 @@ whose delta updates touch only the shards owning affected pairs.
 from __future__ import annotations
 
 import functools
+import itertools
 import json
 import os
 import time
@@ -100,6 +101,7 @@ from repro.core.planner import (
     pad_and_pack, pair_space, postprune_pair_counts)
 from repro.core.plan_stream import (
     PlanChunker, ShardSchedule, ShardStreamPipeline, WindowBatcher)
+from repro.core.spans import span
 
 #: work-item emission modes: ``device`` streams O(pairs) descriptors and
 #: expands pairs→items in-kernel (the default); ``host`` materializes and
@@ -542,15 +544,20 @@ class EngineStats:
     retired_devices: list = field(default_factory=list)
     #: windows restored from a checkpoint journal instead of re-executed
     resumed_windows: int = 0
-    #: host planning walltime of the run, split by phase: pair-space
-    #: maintenance (full ``pair_space`` rebuild, or the delta-incremental
-    #: index edit + affected-pair discovery when ``indexed``), the
-    #: ``apply_delta`` CSR/pair-code diff, and host-side work emission
-    #: (item materialization / descriptor-window construction, measured
-    #: inside the dispatch loop so device wait time is excluded)
+    #: host walltime of the run, split by phase (each the sum of its
+    #: :func:`repro.core.spans.span` durations): pair-space maintenance
+    #: (full ``pair_space`` rebuild and closed-form bases, or the
+    #: delta-incremental index edit + affected-pair discovery when
+    #: ``indexed``), the ``apply_delta`` CSR/pair-code diff, host-side
+    #: work emission (item materialization / descriptor-window
+    #: construction, device wait excluded; summed over the partitioned
+    #: run's producer threads), LPT partitioning + shard extraction, and
+    #: landing (the host blocked on a device result, then its int64 merge)
     host_pair_seconds: float = 0.0
     host_merge_seconds: float = 0.0
     host_emit_seconds: float = 0.0
+    host_partition_seconds: float = 0.0
+    host_land_seconds: float = 0.0
     #: True when the run's pair space came from the session's persistent
     #: :class:`~repro.core.pair_index.PairSpaceIndex` instead of a full
     #: O(P) rebuild
@@ -611,6 +618,8 @@ class EngineStats:
             part += (f" host[pair={self.host_pair_seconds * 1e3:.2f}ms"
                      f" merge={self.host_merge_seconds * 1e3:.2f}ms"
                      f" emit={self.host_emit_seconds * 1e3:.2f}ms"
+                     f" partition={self.host_partition_seconds * 1e3:.2f}ms"
+                     f" land={self.host_land_seconds * 1e3:.2f}ms"
                      f"{' indexed' if self.indexed else ''}]")
         return (f"{self.backend} [{mode} emit={self.emit}] "
                 f"chunks={self.chunks} items={self.items} "
@@ -813,6 +822,9 @@ class CensusEngine:
                                  else float(watchdog_timeout))
         self.faults = faults
         self.stats: EngineStats | None = None
+        #: ids of the censuses (runs, session censuses and updates) this
+        #: engine starts: every host span of one census carries its id
+        self._census_ids = itertools.count(1)
 
     @property
     def ndev(self) -> int:
@@ -931,25 +943,29 @@ class CensusEngine:
             raise ValueError(
                 "checkpoint/resume is supported on partitioned async "
                 "runs (partition=True, schedule='async')")
+        census = next(self._census_ids)
         if self.partition:
             return self._run_partitioned(g, max_items=max_items,
                                          orient=orient,
                                          prune_self=prune_self,
                                          progress=progress, emit=emit,
                                          schedule=schedule, part=part,
-                                         checkpoint=checkpoint)
-        if emit == "device":
-            chunker = PlanChunker(g, max_items, orient=orient,
-                                  pad_to=self.ndev, prune_self=prune_self)
-            return self._run_stream_desc(chunker, progress,
-                                         max_items=max_items)
-        if max_items is None:
+                                         checkpoint=checkpoint,
+                                         census=census)
+        if emit == "host" and max_items is None:
             plan = build_plan(g, pad_to=self.ndev, orient=orient,
                               prune_self=prune_self)
             return self.run_plan(plan)
-        chunker = PlanChunker(g, max_items, orient=orient,
-                              pad_to=self.ndev, prune_self=prune_self)
-        return self._run_stream(chunker, progress)
+        with span("census.plan", census=census) as plan:
+            chunker = PlanChunker(g, max_items, orient=orient,
+                                  pad_to=self.ndev, prune_self=prune_self)
+        if emit == "device":
+            return self._run_stream_desc(chunker, progress,
+                                         max_items=max_items,
+                                         census=census,
+                                         plan_seconds=plan.seconds)
+        return self._run_stream(chunker, progress, census=census,
+                                plan_seconds=plan.seconds)
 
     def resume(self, g: CompactDigraph, checkpoint: str,
                **kwargs) -> np.ndarray:
@@ -1074,10 +1090,11 @@ class CensusEngine:
                              prune_self=prune_self,
                              max_items=max_items, emit=emit, index=index)
 
-    def _run_stream(self, chunker: PlanChunker, progress) -> np.ndarray:
+    def _run_stream(self, chunker: PlanChunker, progress, *, census: int,
+                    plan_seconds: float) -> np.ndarray:
         space = chunker.space
         gbytes = replicated_graph_bytes(space)
-        self.stats = EngineStats(
+        self.stats = st = EngineStats(
             backend=self.backend, ndev=self.ndev, orient=space.orient,
             streamed=True, max_items=chunker.max_items,
             chunks=chunker.num_chunks, chunk_shape=chunker.chunk_shape,
@@ -1087,7 +1104,8 @@ class CensusEngine:
             # multiple of ndev): physical per-device upload bytes
             plan_upload_bytes=ITEM_BYTES * chunker.chunk_shape
             // self.ndev,
-            graph_resident_bytes=gbytes, graph_replicated_bytes=gbytes)
+            graph_resident_bytes=gbytes, graph_replicated_bytes=gbytes,
+            host_pair_seconds=plan_seconds)
         if chunker.num_chunks == 0:
             return assemble_counts(space.n, 0, 0, np.zeros(64, np.int64),
                                    np.zeros(2, np.int64))
@@ -1095,8 +1113,9 @@ class CensusEngine:
         rep, item_sh = self._shardings()
         # chunk-invariant graph + pair arrays: uploaded once, reused by
         # every chunk step (replicated across the mesh when sharded)
-        graph_dev = tuple(self._put(a, rep)
-                          for a in chunker.device_arrays())
+        with span("census.upload", census=census):
+            graph_dev = tuple(self._put(a, rep)
+                              for a in chunker.device_arrays())
 
         hist_acc = np.zeros(64, np.int64)
         inter_acc = np.zeros(2, np.int64)
@@ -1105,7 +1124,19 @@ class CensusEngine:
         step = _chunk_step(self.mesh)
         cache0 = _jit_cache_size(step)
         pending = None
-        for chunk in chunker:
+
+        def land(fut, k):
+            with span("chunk.land", st, "host_land_seconds",
+                      census=census, chunk=k):
+                np.add(hist_acc, np.asarray(fut[0], dtype=np.int64),
+                       out=hist_acc)
+                np.add(inter_acc, np.asarray(fut[1], dtype=np.int64),
+                       out=inter_acc)
+
+        for k in range(chunker.num_chunks):
+            with span("chunk.emit", st, "host_emit_seconds",
+                      census=census, chunk=k):
+                chunk = chunker.chunk(k)
             base_asym += chunk.base_asym
             base_mut += chunk.base_mut
             chunk_items.append(chunk.num_items)
@@ -1118,29 +1149,30 @@ class CensusEngine:
                 continue
             # upload + dispatch chunk k while chunk k-1 still computes
             # (dispatch is async; we only block when accumulating k-1)
-            sp_dev = self._put(chunk.item_sp, item_sh)
-            pv_dev = self._put(chunk.item_pv, item_sh)
-            fut = _launch(step, self.backend, *graph_dev, sp_dev, pv_dev,
-                          self.mesh, space.search_iters, self.backend)
+            with span("chunk.dispatch", census=census, chunk=k):
+                sp_dev = self._put(chunk.item_sp, item_sh)
+                pv_dev = self._put(chunk.item_pv, item_sh)
+                fut = _launch(step, self.backend, *graph_dev, sp_dev,
+                              pv_dev, self.mesh, space.search_iters,
+                              self.backend)
             if pending is not None:
-                hist_acc += np.asarray(pending[0], dtype=np.int64)
-                inter_acc += np.asarray(pending[1], dtype=np.int64)
-            pending = fut
+                land(*pending)
+            pending = fut, k
         if pending is not None:
-            hist_acc += np.asarray(pending[0], dtype=np.int64)
-            inter_acc += np.asarray(pending[1], dtype=np.int64)
+            land(*pending)
 
-        st = self.stats
         st.step_compiles = _jit_cache_size(step) - cache0
         st.chunk_items = chunk_items
         st.items = int(sum(chunk_items))
         mono_wp = -(-st.items // self.ndev) * self.ndev
         st.monolithic_plan_bytes = ITEM_BYTES * mono_wp
-        return assemble_counts(space.n, base_asym, base_mut,
-                               hist_acc, inter_acc)
+        with span("census.assemble", census=census):
+            return assemble_counts(space.n, base_asym, base_mut,
+                                   hist_acc, inter_acc)
 
-    def _run_stream_desc(self, chunker: PlanChunker, progress,
-                         max_items: int | None) -> np.ndarray:
+    def _run_stream_desc(self, chunker: PlanChunker, progress, *,
+                         max_items: int | None, census: int,
+                         plan_seconds: float) -> np.ndarray:
         """Device-emission stream: per chunk the host ships the O(pairs)
         descriptor window; the device expands pairs→items in-kernel
         against the resident flat-index array.  Bit-identical to
@@ -1154,26 +1186,28 @@ class CensusEngine:
         upload = (DESC_BYTES * chunker.desc_shape
                   + 4 * chunker.num_anchors + 4)
         gbytes = replicated_graph_bytes(space)
-        self.stats = EngineStats(
+        self.stats = st = EngineStats(
             backend=self.backend, ndev=self.ndev, orient=space.orient,
             streamed=max_items is not None, max_items=max_items,
             chunks=chunker.num_chunks, chunk_shape=chunker.chunk_shape,
             items=0, peak_plan_bytes=ITEM_BYTES * chunker.chunk_shape,
             emit="device", desc_shape=chunker.desc_shape,
             plan_upload_bytes=upload,
-            graph_resident_bytes=gbytes, graph_replicated_bytes=gbytes)
+            graph_resident_bytes=gbytes, graph_replicated_bytes=gbytes,
+            host_pair_seconds=plan_seconds)
         if chunker.num_chunks == 0:
             return assemble_counts(space.n, 0, 0, np.zeros(64, np.int64),
                                    np.zeros(2, np.int64))
 
         rep, item_sh = self._shardings()
-        graph_dev = tuple(self._put(a, rep)
-                          for a in chunker.device_arrays())
-        # the flat item-index space: created on device once, reused by
-        # every chunk (this is the array the mesh shards — there are no
-        # item arrays left to shard)
-        idx_dev = self._put(jnp.arange(chunker.chunk_shape, dtype=jnp.int32),
-                            item_sh)
+        with span("census.upload", census=census):
+            graph_dev = tuple(self._put(a, rep)
+                              for a in chunker.device_arrays())
+            # the flat item-index space: created on device once, reused
+            # by every chunk (this is the array the mesh shards — there
+            # are no item arrays left to shard)
+            idx_dev = self._put(
+                jnp.arange(chunker.chunk_shape, dtype=jnp.int32), item_sh)
 
         hist_acc = np.zeros(64, np.int64)
         inter_acc = np.zeros(2, np.int64)
@@ -1183,40 +1217,45 @@ class CensusEngine:
         pending = None
 
         def land(fut, k):
-            num = _land_desc_partials(fut, hist_acc, inter_acc,
-                                      chunk_items)
+            with span("chunk.land", st, "host_land_seconds",
+                      census=census, chunk=k):
+                num = _land_desc_partials(fut, hist_acc, inter_acc,
+                                          chunk_items)
             if progress is not None:
                 progress(k, chunker.num_chunks, num)
 
         for k in range(chunker.num_chunks):
-            ba, bm = chunker.bases(k)
-            base_asym += ba
-            base_mut += bm
-            win = chunker.descriptors(k)
-            words = self._put(win.device_words(), rep)
-            fut = _launch(_desc_step, self.backend, *graph_dev, words,
-                          idx_dev, self.mesh, space.search_iters,
-                          chunker.desc_iters, self.backend,
-                          space.orient, space.prune_self)
+            with span("chunk.emit", st, "host_emit_seconds",
+                      census=census, chunk=k):
+                ba, bm = chunker.bases(k)
+                base_asym += ba
+                base_mut += bm
+                words = chunker.descriptors(k).device_words()
+            with span("chunk.dispatch", census=census, chunk=k):
+                fut = _launch(_desc_step, self.backend, *graph_dev,
+                              self._put(words, rep), idx_dev, self.mesh,
+                              space.search_iters, chunker.desc_iters,
+                              self.backend, space.orient,
+                              space.prune_self)
             if pending is not None:
                 land(pending, k - 1)
             pending = fut
         if pending is not None:
             land(pending, chunker.num_chunks - 1)
 
-        st = self.stats
         st.step_compiles = _jit_cache_size(_desc_step) - cache0
         st.chunk_items = chunk_items
         st.items = int(sum(chunk_items))
         mono_wp = -(-st.items // self.ndev) * self.ndev
         st.monolithic_plan_bytes = ITEM_BYTES * mono_wp
-        return assemble_counts(space.n, base_asym, base_mut,
-                               hist_acc, inter_acc)
+        with span("census.assemble", census=census):
+            return assemble_counts(space.n, base_asym, base_mut,
+                                   hist_acc, inter_acc)
 
     def _run_partitioned(self, g: CompactDigraph, *,
                          max_items: int | None, orient: str,
                          prune_self: bool, progress, emit: str,
-                         schedule: str, part=None,
+                         schedule: str, census: int, part=None,
                          checkpoint: str | None = None) -> np.ndarray:
         """Partitioned plan + count: LPT-shard the pair space (or take a
         prebuilt ``part``), extract one local subgraph per mesh device,
@@ -1232,13 +1271,11 @@ class CensusEngine:
         paths for every backend, orient, emit and schedule (the
         relabeling is order-preserving, the pair partition is exact, and
         the partials are integer sums — merge order cannot matter)."""
+        plan_seconds = 0.0
         if part is None:
-            space = pair_space(g, orient=orient, prune_self=prune_self)
-            part = (partition_graph_2d(space=space,
-                                       mesh_shape=self.partition_2d)
-                    if self.partition_2d is not None
-                    else partition_graph(num_shards=self.ndev,
-                                         space=space))
+            with span("census.plan", census=census) as plan:
+                space = pair_space(g, orient=orient, prune_self=prune_self)
+            plan_seconds = plan.seconds
         elif part.num_shards != self.ndev:
             raise ValueError(
                 f"prebuilt partition has {part.num_shards} shards for "
@@ -1249,19 +1286,29 @@ class CensusEngine:
                 f"prebuilt partition mesh "
                 f"{getattr(part, 'mesh_shape', None)} does not match "
                 f"partition_2d={self.partition_2d}")
+        with span("census.partition", census=census) as partition:
+            if part is None:
+                part = (partition_graph_2d(space=space,
+                                           mesh_shape=self.partition_2d)
+                        if self.partition_2d is not None
+                        else partition_graph(num_shards=self.ndev,
+                                             space=space))
+            sched = ShardSchedule([sh.space for sh in part.shards],
+                                  max_items, self.ndev,
+                                  mesh_shape=getattr(part, "mesh_shape",
+                                                     None))
+        host = {"host_pair_seconds": plan_seconds,
+                "host_partition_seconds": partition.seconds}
         space = part.space
-        sched = ShardSchedule([sh.space for sh in part.shards],
-                              max_items, self.ndev,
-                              mesh_shape=getattr(part, "mesh_shape",
-                                                 None))
         upload = (4 * (1 + 3 * sched.desc_shape + sched.num_anchors)
                   if emit == "device"
                   else ITEM_BYTES * sched.chunk_shape)
         if schedule == "async":
             return self._run_partitioned_async(part, sched, progress,
                                                emit, max_items, upload,
-                                               checkpoint=checkpoint)
-        self.stats = EngineStats(
+                                               checkpoint=checkpoint,
+                                               census=census, host=host)
+        self.stats = st = EngineStats(
             backend=self.backend, ndev=self.ndev, orient=space.orient,
             streamed=max_items is not None, max_items=max_items,
             chunks=sched.num_steps,
@@ -1291,38 +1338,49 @@ class CensusEngine:
             # drain), so the max is the non-empty shard count
             windows_per_dispatch_max=sum(
                 1 for t in sched.shard_steps if t > 0),
-            dispatch_batch_limit=1)
-        base_asym, base_mut = global_bases(space)
+            dispatch_batch_limit=1, **host)
+        with span("census.plan", st, "host_pair_seconds", census=census):
+            base_asym, base_mut = global_bases(space)
         if sched.num_steps == 0:
             return assemble_counts(space.n, base_asym, base_mut,
                                    np.zeros(64, np.int64),
                                    np.zeros(2, np.int64))
 
         rep, dev_sh = self._shardings()
-        graph_dev = tuple(self._put(a, dev_sh)
-                          for a in stacked_device_arrays(part.shards))
+        with span("census.partition", st, "host_partition_seconds",
+                  census=census):
+            arrs = stacked_device_arrays(part.shards)
+        with span("census.upload", census=census):
+            graph_dev = tuple(self._put(a, dev_sh) for a in arrs)
+            if emit == "device":
+                idx_dev = self._put(
+                    jnp.arange(sched.chunk_shape, dtype=jnp.int32), rep)
         hist_acc = np.zeros(64, np.int64)
         inter_acc = np.zeros(2, np.int64)
         chunk_items: list[int] = []
         pending = None
         if emit == "device":
-            idx_dev = self._put(
-                jnp.arange(sched.chunk_shape, dtype=jnp.int32), rep)
             step = _part_desc_step
             cache0 = _jit_cache_size(step)
 
             def land(fut, k):
-                num = _land_desc_partials(fut, hist_acc, inter_acc,
-                                          chunk_items)
+                with span("chunk.land", st, "host_land_seconds",
+                          census=census, chunk=k):
+                    num = _land_desc_partials(fut, hist_acc, inter_acc,
+                                              chunk_items)
                 if progress is not None:
                     progress(k, sched.num_steps, num)
 
             for k in range(sched.num_steps):
-                words = self._put(sched.step_words(k), dev_sh)
-                fut = _launch(step, self.backend, *graph_dev, words,
-                              idx_dev, self.mesh, space.search_iters,
-                              sched.desc_iters, self.backend,
-                              space.orient, space.prune_self)
+                with span("chunk.emit", st, "host_emit_seconds",
+                          census=census, chunk=k):
+                    words = sched.step_words(k)
+                with span("chunk.dispatch", census=census, chunk=k):
+                    fut = _launch(step, self.backend, *graph_dev,
+                                  self._put(words, dev_sh), idx_dev,
+                                  self.mesh, space.search_iters,
+                                  sched.desc_iters, self.backend,
+                                  space.orient, space.prune_self)
                 if pending is not None:
                     land(pending, k - 1)
                 pending = fut
@@ -1331,36 +1389,47 @@ class CensusEngine:
         else:
             step = _part_chunk_step
             cache0 = _jit_cache_size(step)
+
+            def land(fut, k):
+                with span("chunk.land", st, "host_land_seconds",
+                          census=census, chunk=k):
+                    np.add(hist_acc, np.asarray(fut[0], dtype=np.int64),
+                           out=hist_acc)
+                    np.add(inter_acc, np.asarray(fut[1], dtype=np.int64),
+                           out=inter_acc)
+
             for k in range(sched.num_steps):
-                item_sp, item_pv, nums = sched.step_items(k)
+                with span("chunk.emit", st, "host_emit_seconds",
+                          census=census, chunk=k):
+                    item_sp, item_pv, nums = sched.step_items(k)
                 chunk_items.append(int(sum(nums)))
                 if progress is not None:
                     progress(k, sched.num_steps, chunk_items[-1])
-                fut = _launch(step, self.backend, *graph_dev,
-                              self._put(item_sp, dev_sh),
-                              self._put(item_pv, dev_sh),
-                              self.mesh, space.search_iters, self.backend)
+                with span("chunk.dispatch", census=census, chunk=k):
+                    fut = _launch(step, self.backend, *graph_dev,
+                                  self._put(item_sp, dev_sh),
+                                  self._put(item_pv, dev_sh),
+                                  self.mesh, space.search_iters,
+                                  self.backend)
                 if pending is not None:
-                    hist_acc += np.asarray(pending[0], dtype=np.int64)
-                    inter_acc += np.asarray(pending[1], dtype=np.int64)
+                    land(pending, k - 1)
                 pending = fut
             if pending is not None:
-                hist_acc += np.asarray(pending[0], dtype=np.int64)
-                inter_acc += np.asarray(pending[1], dtype=np.int64)
+                land(pending, sched.num_steps - 1)
 
-        st = self.stats
         st.step_compiles = _jit_cache_size(step) - cache0
         st.chunk_items = chunk_items
         st.items = int(sum(chunk_items))
         mono_wp = -(-st.items // self.ndev) * self.ndev
         st.monolithic_plan_bytes = ITEM_BYTES * mono_wp
-        return assemble_counts(space.n, base_asym, base_mut,
-                               hist_acc, inter_acc)
+        with span("census.assemble", census=census):
+            return assemble_counts(space.n, base_asym, base_mut,
+                                   hist_acc, inter_acc)
 
     def _run_partitioned_async(self, part, sched: ShardSchedule,
                                progress, emit: str,
                                max_items: int | None,
-                               upload: int,
+                               upload: int, *, census: int, host: dict,
                                checkpoint: str | None = None
                                ) -> np.ndarray:
         """Async per-shard streams: every device drains its PRIVATE chunk
@@ -1432,7 +1501,7 @@ class CensusEngine:
         cap = (max(1, min(self.max_windows_per_dispatch,
                           max(sched.shard_steps, default=0)))
                if emit == "device" else 1)
-        self.stats = EngineStats(
+        self.stats = st = EngineStats(
             backend=self.backend, ndev=ndev, orient=space.orient,
             streamed=max_items is not None, max_items=max_items,
             chunks=0, chunk_shape=sched.chunk_shape, items=0,
@@ -1448,8 +1517,9 @@ class CensusEngine:
             graph_replicated_bytes=part.stats.replicated_bytes,
             schedule="async", shard_steps=[0] * ndev,
             pipeline_depth=self.pipeline_depth,
-            dispatch_batch_limit=cap)
-        base_asym, base_mut = global_bases(space)
+            dispatch_batch_limit=cap, **host)
+        with span("census.plan", st, "host_pair_seconds", census=census):
+            base_asym, base_mut = global_bases(space)
         if total_windows == 0:
             return assemble_counts(space.n, base_asym, base_mut,
                                    np.zeros(64, np.int64),
@@ -1470,9 +1540,15 @@ class CensusEngine:
         # shapes across shards, so ONE compiled single-device step serves
         # every shard's every window); the host copies in ``arrs`` stay
         # alive as the failover re-upload source
-        arrs = stacked_device_arrays(part.shards)
-        dev = [tuple(jax.device_put(a[s], devices[s]) for a in arrs)
-               for s in range(ndev)]
+        with span("census.partition", st, "host_partition_seconds",
+                  census=census):
+            arrs = stacked_device_arrays(part.shards)
+        with span("census.upload", census=census):
+            dev = [tuple(jax.device_put(a[s], devices[s]) for a in arrs)
+                   for s in range(ndev)]
+            idx = ([jax.device_put(np.arange(sched.chunk_shape,
+                                             dtype=np.int32), d)
+                    for d in devices] if emit == "device" else None)
         #: shard → device currently serving it (failover re-routes)
         home = list(range(ndev))
         retired: set = set()
@@ -1481,9 +1557,6 @@ class CensusEngine:
         batcher = None
         if emit == "device":
             step = _desc_megastep(self.mesh)
-            idx = [jax.device_put(
-                np.arange(sched.chunk_shape, dtype=np.int32), d)
-                for d in devices]
             batcher = WindowBatcher(
                 cap, 1 + 3 * sched.desc_shape + sched.num_anchors)
             # remaining window ids per shard in yield order — lets the
@@ -1501,7 +1574,10 @@ class CensusEngine:
                             continue
                         if injector is not None:
                             injector.fire("producer", shard=s)
-                        yield sched.descriptors(s, k).device_words()
+                        with span("chunk.emit", st, "host_emit_seconds",
+                                  census=census, chunk=k, shard=s):
+                            words = sched.descriptors(s, k).device_words()
+                        yield words
                 return gen()
         else:
             step = _chunk_step(self.mesh)
@@ -1514,7 +1590,9 @@ class CensusEngine:
                     for k in range(sched.steps_for(s)):
                         if done is not None and k in done[s]:
                             continue
-                        sp, pv, num = sched.shard_step_items(s, k)
+                        with span("chunk.emit", st, "host_emit_seconds",
+                                  census=census, chunk=k, shard=s):
+                            sp, pv, num = sched.shard_step_items(s, k)
                         if num == 0:
                             # fully-pruned window: zero contribution by
                             # construction — never dispatched
@@ -1542,7 +1620,6 @@ class CensusEngine:
         win_max = 0
         pad_windows = 0
         landed = [self.stats.resumed_windows]
-        st = self.stats
 
         def retire(d_id: int, cause) -> None:
             """Fail device ``d_id`` over to the survivors: every shard
@@ -1630,6 +1707,23 @@ class CensusEngine:
 
         def land(job) -> None:
             s, window, ids, fut, x, attempts, poisoned = job
+            with span("chunk.land", st, "host_land_seconds",
+                      census=census, chunk=ids[0], shard=s):
+                hsum, isum, nums = fetch(s, window, fut, x, attempts,
+                                         poisoned)
+                np.add(hist_acc, hsum, out=hist_acc)
+                np.add(inter_acc, isum, out=inter_acc)
+            if journal is not None:
+                journal.record(s, ids, hsum, isum, nums)
+            for num in nums:
+                chunk_items.append(num)
+                if progress is not None:
+                    progress(landed[0], total_windows, num)
+                landed[0] += 1
+
+        def fetch(s, window, fut, x, attempts, poisoned):
+            """One dispatch's validated partials, summed through int64:
+            ``(hist64, inter2, per-window valid items)``."""
             while True:
                 try:
                     if emit == "device":
@@ -1643,18 +1737,15 @@ class CensusEngine:
                             hist64s, inter3s = poison_result(hist64s,
                                                              inter3s)
                         _validate_partials(hist64s[:x], inter3s[:x])
-                        hsum = hist64s[:x].sum(axis=0)
-                        isum = inter3s[:x, :2].sum(axis=0)
-                        nums = [int(inter3s[i, 2]) for i in range(x)]
-                    else:
-                        h = np.asarray(fut[0], dtype=np.int64)
-                        it2 = np.asarray(fut[1], dtype=np.int64)
-                        if poisoned:
-                            h, it2 = poison_result(h, it2)
-                        _validate_partials(h, it2)
-                        hsum, isum = h, it2
-                        nums = [x]
-                    break
+                        return (hist64s[:x].sum(axis=0),
+                                inter3s[:x, :2].sum(axis=0),
+                                [int(inter3s[i, 2]) for i in range(x)])
+                    h = np.asarray(fut[0], dtype=np.int64)
+                    it2 = np.asarray(fut[1], dtype=np.int64)
+                    if poisoned:
+                        h, it2 = poison_result(h, it2)
+                    _validate_partials(h, it2)
+                    return h, it2, [x]
                 except Exception as exc:
                     # fetch/validation failure: re-dispatch the SAME
                     # window (same-device retry, then failover) — the
@@ -1670,15 +1761,6 @@ class CensusEngine:
                                    * 2 ** (attempts - 1))
                     fut, poisoned, attempts = dispatch_retrying(
                         s, window, attempts)
-            np.add(hist_acc, hsum, out=hist_acc)
-            np.add(inter_acc, isum, out=inter_acc)
-            if journal is not None:
-                journal.record(s, ids, hsum, isum, nums)
-            for num in nums:
-                chunk_items.append(num)
-                if progress is not None:
-                    progress(landed[0], total_windows, num)
-                landed[0] += 1
 
         def restart(slot: int, skip: int):
             return make_source(live[slot], skip)
@@ -1687,7 +1769,8 @@ class CensusEngine:
             [make_source(s) for s in live], depth=self.pipeline_depth,
             batch=batcher, restart=restart,
             watchdog=self.watchdog_timeout,
-            max_retries=self.max_retries, backoff=self.retry_backoff)
+            max_retries=self.max_retries, backoff=self.retry_backoff,
+            census=census)
         pending: deque = deque()
         limit = 2 * ndev
         try:
@@ -1706,7 +1789,10 @@ class CensusEngine:
                         ids = [wid]
                         shard_steps[s] += 1
                         win_max = max(win_max, 1)
-                    fut, poisoned, attempts = dispatch_retrying(s, window)
+                    with span("chunk.dispatch", census=census,
+                              chunk=ids[0], shard=s):
+                        fut, poisoned, attempts = dispatch_retrying(
+                            s, window)
                     dispatches += 1
                     pending.append(
                         (s, window, ids, fut, x, attempts, poisoned))
@@ -1734,8 +1820,9 @@ class CensusEngine:
         st.plan_pad_bytes_total = upload * pad_windows
         mono_wp = -(-st.items // ndev) * ndev
         st.monolithic_plan_bytes = ITEM_BYTES * mono_wp
-        return assemble_counts(space.n, base_asym, base_mut,
-                               hist_acc, inter_acc)
+        with span("census.assemble", census=census):
+            return assemble_counts(space.n, base_asym, base_mut,
+                                   hist_acc, inter_acc)
 
 
 def _pad_i32(a: np.ndarray, cap: int) -> np.ndarray:
@@ -1745,25 +1832,19 @@ def _pad_i32(a: np.ndarray, cap: int) -> np.ndarray:
     return out
 
 
-class _TimedIter:
-    """Wrap an iterator, accumulating the walltime spent *inside*
-    ``next()`` — the host-side plan/window construction cost of a lazy
-    emission stream, excluding the consumer's device-wait time (the
-    ``host_emit_seconds`` stats bucket)."""
-
-    def __init__(self, it):
-        self._it = iter(it)
-        self.seconds = 0.0
-
-    def __iter__(self):
-        return self
-
-    def __next__(self):
-        t0 = time.perf_counter()
-        try:
-            return next(self._it)
-        finally:
-            self.seconds += time.perf_counter() - t0
+def _emit_spans(it, session, **ids):
+    """Yield ``it``'s items, each built inside a ``chunk.emit`` span
+    timed into the session's emit counter: the host-side plan/window
+    construction cost of a lazy emission stream, excluding the
+    consumer's device-wait time."""
+    it = iter(it)
+    for k in itertools.count():
+        with span("chunk.emit", session, "_t_emit",
+                  census=session._census_id, chunk=k, **ids):
+            item = next(it, None)
+        if item is None:
+            return
+        yield item
 
 
 def _split_capacity_compiles(session, chunk_items: list, compiles: int
@@ -1929,7 +2010,11 @@ class EngineSession:
         #: rebuilding the O(P) pair space (False == rebuild oracle)
         self.use_index = bool(index)
         self._pair_index: PairSpaceIndex | None = None
+        #: host-phase counters of the operation in progress (its spans'
+        #: buckets), handed to its :class:`EngineStats` and reset
         self._t_pair = self._t_merge = self._t_emit = 0.0
+        #: id of the census or update in progress, carried by its spans
+        self._census_id = next(engine._census_ids)
         #: pinned unrolled-search depth: any row has < n entries, so this
         #: upper bound keeps the jitted step valid for every graph revision
         self.search_iters = max(1, int(np.ceil(np.log2(max(g.n, 2)))))
@@ -2029,15 +2114,15 @@ class EngineSession:
         (re)upload the padded device arrays."""
         self._g = g
         if space is None:
-            t0 = time.perf_counter()
-            if self.use_index:
-                self._pair_index = PairSpaceIndex(
-                    g, orient=self.orient, prune_self=self.prune_self)
-                space = self._pair_index.space
-            else:
-                space = pair_space(g, orient=self.orient,
-                                   prune_self=self.prune_self)
-            self._t_pair += time.perf_counter() - t0
+            with span("census.plan", self, "_t_pair",
+                      census=self._census_id):
+                if self.use_index:
+                    self._pair_index = PairSpaceIndex(
+                        g, orient=self.orient, prune_self=self.prune_self)
+                    space = self._pair_index.space
+                else:
+                    space = pair_space(g, orient=self.orient,
+                                       prune_self=self.prune_self)
         self._space = space
         self._full_items: int | None = None   # lazy per-install stat
         if self.chunk_shape is None:
@@ -2071,6 +2156,7 @@ class EngineSession:
         Invalidates the running census until :meth:`census` recomputes."""
         if g.n != self.n:
             raise ValueError(f"session is pinned to n={self.n}, got {g.n}")
+        self._census_id = next(self.engine._census_ids)
         self._install(g)
         self._census = None
         self.last_delta = None
@@ -2193,18 +2279,15 @@ class EngineSession:
         base_asym, base_mut = base_for_pairs(self._space, pair_ids)
         if self.emit == "device":
             ids = np.asarray(pair_ids, dtype=np.int64).ravel()
-            wins = _TimedIter(
+            hist, inter, chunk_items = self._run_desc_batches(_emit_spans(
                 subset_descriptor_windows(self._space, ids,
                                           self.chunk_shape,
                                           self.desc_shape,
-                                          self.num_anchors))
-            hist, inter, chunk_items = self._run_desc_batches(wins)
-            self._t_emit += wins.seconds
+                                          self.num_anchors), self))
             return (contribution_counts(base_asym, base_mut, hist, inter),
                     int(sum(chunk_items)), chunk_items)
-        t0 = time.perf_counter()
-        items = emit_items_for_pairs(self._space, pair_ids)
-        self._t_emit += time.perf_counter() - t0
+        with span("chunk.emit", self, "_t_emit", census=self._census_id):
+            items = emit_items_for_pairs(self._space, pair_ids)
         num_items = int(items[0].shape[0])
         if num_items == 0:
             return (contribution_counts(base_asym, base_mut,
@@ -2274,22 +2357,20 @@ class EngineSession:
         emission only descriptor windows are built — O(pairs-per-window)
         host memory and upload."""
         self._check_open()
+        self._census_id = next(self.engine._census_ids)
         space = self._space
         cache0 = self._cache_size()
         w0 = space.num_items_preprune
         cs = self.chunk_shape
         if self.emit == "device":
-            wins = _TimedIter(
+            hist, inter, chunk_items = self._run_desc_batches(_emit_spans(
                 iter_descriptor_windows(space.offsets, cs,
                                         self.desc_shape,
-                                        self.num_anchors))
-            hist, inter, chunk_items = self._run_desc_batches(wins)
-            self._t_emit += wins.seconds
+                                        self.num_anchors), self))
         else:
-            batches = _TimedIter(emit_items(space, lo, min(lo + cs, w0))
-                                 for lo in range(0, w0, cs))
-            hist, inter, chunk_items = self._run_batches(batches)
-            self._t_emit += batches.seconds
+            hist, inter, chunk_items = self._run_batches(_emit_spans(
+                (emit_items(space, lo, min(lo + cs, w0))
+                 for lo in range(0, w0, cs)), self))
         base_asym, base_mut = global_bases(space)
         self._census = assemble_counts(self.n, base_asym, base_mut,
                                        hist, inter)
@@ -2309,11 +2390,11 @@ class EngineSession:
         if self._census is None:
             raise RuntimeError(
                 "no baseline census: call census() before update()")
+        self._census_id = next(self.engine._census_ids)
         cache0 = self._cache_size()
-        t0 = time.perf_counter()
-        g_new, delta = apply_delta(self._g, add_src, add_dst,
-                                   del_src, del_dst)
-        self._t_merge += time.perf_counter() - t0
+        with span("delta.merge", self, "_t_merge", census=self._census_id):
+            g_new, delta = apply_delta(self._g, add_src, add_dst,
+                                       del_src, del_dst)
         self.last_delta = delta
         if delta.num_changed == 0:
             # nothing changed: no recount, no descriptor/item upload, no
@@ -2322,26 +2403,24 @@ class EngineSession:
                             self._cache_size() - cache0)
             return self._census.copy()
 
-        t0 = time.perf_counter()
-        aff_old = (self._pair_index.affected_pair_ids(delta.touched)
-                   if self.use_index
-                   else affected_pair_ids(self._space, delta.touched))
-        self._t_pair += time.perf_counter() - t0
+        with span("census.plan", self, "_t_pair", census=self._census_id):
+            aff_old = (self._pair_index.affected_pair_ids(delta.touched)
+                       if self.use_index
+                       else affected_pair_ids(self._space, delta.touched))
         contrib_old, items_old, chunks_old = self._subset(aff_old)
         if self.use_index:
             # edit the persistent index into the new graph's pair space
             # (O(delta · log P + affected)) instead of rebuilding O(P)
-            t0 = time.perf_counter()
-            space_new = self._pair_index.apply(delta, g_new)
-            self._t_pair += time.perf_counter() - t0
+            with span("census.plan", self, "_t_pair",
+                      census=self._census_id):
+                space_new = self._pair_index.apply(delta, g_new)
             self._install(g_new, space=space_new)
         else:
             self._install(g_new)
-        t0 = time.perf_counter()
-        aff_new = (self._pair_index.affected_pair_ids(delta.touched)
-                   if self.use_index
-                   else affected_pair_ids(self._space, delta.touched))
-        self._t_pair += time.perf_counter() - t0
+        with span("census.plan", self, "_t_pair", census=self._census_id):
+            aff_new = (self._pair_index.affected_pair_ids(delta.touched)
+                       if self.use_index
+                       else affected_pair_ids(self._space, delta.touched))
         contrib_new, items_new, chunks_new = self._subset(aff_new)
         self._census = combine(self._census, contrib_old, contrib_new,
                                self.n)
@@ -2437,7 +2516,9 @@ class PartitionedEngineSession:
         #: delta-incremental host planning (see :class:`EngineSession`)
         self.use_index = bool(index)
         self._pair_index: PairSpaceIndex | None = None
+        #: host-phase counters and census id (see :class:`EngineSession`)
         self._t_pair = self._t_merge = self._t_emit = 0.0
+        self._census_id = next(engine._census_ids)
         self._install_full(g)
 
     # ---------------------------------------------------------- lifecycle
@@ -2496,15 +2577,14 @@ class PartitionedEngineSession:
         """(Re)partition ``g`` from scratch and make every shard
         device-resident (session open and :meth:`set_graph`)."""
         self._g = g
-        t0 = time.perf_counter()
-        if self.use_index:
-            self._pair_index = PairSpaceIndex(
-                g, orient=self.orient, prune_self=self.prune_self)
-            space = self._pair_index.space
-        else:
-            space = pair_space(g, orient=self.orient,
-                               prune_self=self.prune_self)
-        self._t_pair += time.perf_counter() - t0
+        with span("census.plan", self, "_t_pair", census=self._census_id):
+            if self.use_index:
+                self._pair_index = PairSpaceIndex(
+                    g, orient=self.orient, prune_self=self.prune_self)
+                space = self._pair_index.space
+            else:
+                space = pair_space(g, orient=self.orient,
+                                   prune_self=self.prune_self)
         self._space = space
         self._full_items: int | None = None
         part = self._make_partition(space)
@@ -2593,6 +2673,7 @@ class PartitionedEngineSession:
         census until :meth:`census` recomputes."""
         if g.n != self.n:
             raise ValueError(f"session is pinned to n={self.n}, got {g.n}")
+        self._census_id = next(self.engine._census_ids)
         self._install_full(g)
         self._census = None
         self.last_delta = None
@@ -2676,14 +2757,14 @@ class PartitionedEngineSession:
         sp = self._shards[s].space
         cs = self.chunk_shape
         if self.emit == "device":
-            wins = _TimedIter(
+            wins = _emit_spans(
                 iter_descriptor_windows(sp.offsets, cs,
                                         self.desc_shape,
                                         self.num_anchors)
                 if pair_ids is None else
                 subset_descriptor_windows(sp, pair_ids, cs,
                                           self.desc_shape,
-                                          self.num_anchors))
+                                          self.num_anchors), self, shard=s)
             for win in wins:
                 if win.num_preprune == 0:
                     continue
@@ -2694,17 +2775,17 @@ class PartitionedEngineSession:
 
                 fut, poisoned = redo()
                 yield fut, poisoned, redo, None
-            self._t_emit += wins.seconds
             return
         if pair_ids is None:
             w0 = sp.num_items_preprune
-            batches = _TimedIter(emit_items(sp, lo, min(lo + cs, w0))
-                                 for lo in range(0, w0, cs))
+            batches = _emit_spans((emit_items(sp, lo, min(lo + cs, w0))
+                                   for lo in range(0, w0, cs)), self,
+                                  shard=s)
         else:
-            t0 = time.perf_counter()
-            items = emit_items_for_pairs(sp, pair_ids)
-            self._t_emit += time.perf_counter() - t0
-            batches = _TimedIter(
+            with span("chunk.emit", self, "_t_emit",
+                      census=self._census_id, shard=s):
+                items = emit_items_for_pairs(sp, pair_ids)
+            batches = (
                 (items[0][lo:lo + cs], items[1][lo:lo + cs],
                  items[2][lo:lo + cs])
                 for lo in range(0, max(int(items[0].shape[0]), 1), cs))
@@ -2719,7 +2800,6 @@ class PartitionedEngineSession:
 
             fut, poisoned = redo()
             yield fut, poisoned, redo, num
-        self._t_emit += batches.seconds
 
     def _job_stream(self, s: int, pair_ids=None):
         """Shard ``s``'s jobs tagged with their shard id (a bound helper,
@@ -2820,6 +2900,7 @@ class PartitionedEngineSession:
         stream on its own device, partials merge on the host.  (Re)bases
         the running C_k that :meth:`update` moves forward."""
         self._check_open()
+        self._census_id = next(self.engine._census_ids)
         cache0 = self._cache_size()
         hist_acc = np.zeros(64, np.int64)
         inter_acc = np.zeros(2, np.int64)
@@ -2901,11 +2982,11 @@ class PartitionedEngineSession:
         if self._census is None:
             raise RuntimeError(
                 "no baseline census: call census() before update()")
+        self._census_id = next(self.engine._census_ids)
         cache0 = self._cache_size()
-        t0 = time.perf_counter()
-        g_new, delta = apply_delta(self._g, add_src, add_dst,
-                                   del_src, del_dst)
-        self._t_merge += time.perf_counter() - t0
+        with span("delta.merge", self, "_t_merge", census=self._census_id):
+            g_new, delta = apply_delta(self._g, add_src, add_dst,
+                                       del_src, del_dst)
         self.last_delta = delta
         if delta.num_changed == 0:
             self._set_stats([], [0] * self.ndev, 0,
@@ -2915,13 +2996,13 @@ class PartitionedEngineSession:
 
         n = self.n
         space_old = self._space
-        t0 = time.perf_counter()
-        if self.use_index:
-            aff_old = self._pair_index.affected_pair_ids(delta.touched)
-        else:
-            aff_old = affected_pair_ids(space_old, delta.touched)
-        aff_keys_old = (space_old.pair_u * n + space_old.pair_v)[aff_old]
-        self._t_pair += time.perf_counter() - t0
+        with span("census.plan", self, "_t_pair", census=self._census_id):
+            if self.use_index:
+                aff_old = self._pair_index.affected_pair_ids(delta.touched)
+            else:
+                aff_old = affected_pair_ids(space_old, delta.touched)
+            aff_keys_old = (space_old.pair_u * n
+                            + space_old.pair_v)[aff_old]
         chunk_items: list[int] = []
         shard_items = [0] * self.ndev
         touched_owner: dict[int, int] = {}
@@ -2931,21 +3012,20 @@ class PartitionedEngineSession:
 
         # ---- reassign ownership and refresh only the dirty shards
         self._g = g_new
-        t0 = time.perf_counter()
-        if self.use_index:
-            # edit the persistent index into the new pair space
-            # (O(delta · log P + affected)) instead of rebuilding O(P);
-            # its maintained keys/costs also feed the owner routing and
-            # the dirty-shard refresh below
-            space_new = self._pair_index.apply(delta, g_new)
-            key_all_new = self._pair_index.keys
-            costs_new = self._pair_index.costs
-        else:
-            space_new = pair_space(g_new, orient=self.orient,
-                                   prune_self=self.prune_self)
-            key_all_new = space_new.pair_u * n + space_new.pair_v
-            costs_new = None
-        self._t_pair += time.perf_counter() - t0
+        with span("census.plan", self, "_t_pair", census=self._census_id):
+            if self.use_index:
+                # edit the persistent index into the new pair space
+                # (O(delta · log P + affected)) instead of rebuilding
+                # O(P); its maintained keys/costs also feed the owner
+                # routing and the dirty-shard refresh below
+                space_new = self._pair_index.apply(delta, g_new)
+                key_all_new = self._pair_index.keys
+                costs_new = self._pair_index.costs
+            else:
+                space_new = pair_space(g_new, orient=self.orient,
+                                       prune_self=self.prune_self)
+                key_all_new = space_new.pair_u * n + space_new.pair_v
+                costs_new = None
         self._space = space_new
         self._full_items = None
         dkeys = delta.pair_lo * n + delta.pair_hi
@@ -2987,13 +3067,12 @@ class PartitionedEngineSession:
 
         # ---- new-side recount (owners of every affected new pair are,
         # by construction, in the refreshed dirty set)
-        t0 = time.perf_counter()
-        if self.use_index:
-            aff_new = self._pair_index.affected_pair_ids(delta.touched)
-        else:
-            aff_new = affected_pair_ids(space_new, delta.touched)
-        aff_keys_new = key_all_new[aff_new]
-        self._t_pair += time.perf_counter() - t0
+        with span("census.plan", self, "_t_pair", census=self._census_id):
+            if self.use_index:
+                aff_new = self._pair_index.affected_pair_ids(delta.touched)
+            else:
+                aff_new = affected_pair_ids(space_new, delta.touched)
+            aff_keys_new = key_all_new[aff_new]
         contrib_new, _ = self._recount(
             aff_keys_new, chunk_items, shard_items)
         self._census = combine(self._census, contrib_old, contrib_new,

@@ -48,6 +48,7 @@ from repro.core.planner import (
     DESC_SEARCH_ITERS, DescriptorWindow, PairSpace, PlanOverflowError,
     descriptor_window, emit_items, max_pairs_per_window, num_desc_anchors,
     pad_and_pack, pair_space)
+from repro.core.spans import span
 
 
 class ProducerStalledError(FaultError):
@@ -418,9 +419,10 @@ class ShardStreamPipeline:
     stream for a shard with zero windows).  When *no* live shard has a
     window ready the consumer blocks on the first live queue and counts
     a **stall** (producer-bound moments, surfaced as
-    ``EngineStats.stall_steps``).  Producer exceptions re-raise in the
-    consumer; :meth:`close` unblocks and joins the threads (the engine
-    closes in a ``finally``).
+    ``EngineStats.stall_steps``; the wait is a ``pipeline.stall`` host
+    span carrying the ``census`` id of the run).  Producer exceptions
+    re-raise in the consumer; :meth:`close` unblocks and joins the
+    threads (the engine closes in a ``finally``).
 
     ``batch`` (optional) is a :class:`WindowBatcher`: each source is
     wrapped so its producer thread coalesces up to the batcher's
@@ -462,13 +464,15 @@ class ShardStreamPipeline:
 
     def __init__(self, sources, depth: int = 2, batch=None, *,
                  restart=None, watchdog: float | None = None,
-                 max_retries: int = 2, backoff: float = 0.01):
+                 max_retries: int = 2, backoff: float = 0.01,
+                 census: int = 0):
         if depth < 1:
             raise ValueError(f"depth must be >= 1, got {depth}")
         if max_retries < 0:
             raise ValueError(f"max_retries must be >= 0, got {max_retries}")
         self.depth = int(depth)
         self.batch = batch
+        self.census = census
         self.stalls = 0
         self.producer_retries = 0
         self.watchdog_fires = 0
@@ -653,7 +657,9 @@ class ShardStreamPipeline:
                 if self.batch is not None and self._consumed:
                     self.batch.shrink()
                 s = min(self._live)
-                got = self._resolve(self._queues[s].get(), s)
+                with span("pipeline.stall", census=self.census, shard=s):
+                    item = self._queues[s].get()
+                got = self._resolve(item, s)
                 if got is not None:
                     yield got
 
