@@ -31,10 +31,11 @@ so any chunking of the work items yields bit-identical censuses).
 :func:`triad_census` below is the thin single-device wrapper.
 
 Work items reach a dispatch in one of two forms: pre-packed item words
-(:func:`census_partials` — host emission) or pair descriptors that the
-device expands back into items itself (:func:`census_partials_desc`, via
-:func:`expand_work_items` — device emission, no host-side item
-materialization).  Both feed the same :func:`classify_items`, and every
+(:func:`census_partials` — host emission, via :func:`gather_work_items`)
+or pair descriptors that the device expands back into items itself
+(:func:`census_partials_desc`, via :func:`expand_work_items` — device
+emission, no host-side item materialization).  Both hand the same
+:class:`WorkItems` to :func:`classify_items`, and every
 item the host-side planner would have pruned is provably a zero
 contribution of the classification masks, which is why the two forms are
 bit-identical on every backend and orient mode.
@@ -43,6 +44,7 @@ bit-identical on every backend and orient mode.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -105,56 +107,94 @@ def segment_searchsorted(keys, lo, hi, q, iters: int):
     return lo
 
 
+class WorkItems(NamedTuple):
+    """Per-lane fields of a batch of work items, each gathered once.
+
+    ``u``/``v`` are the pair's endpoints and ``pair_code`` its packed
+    dyad code; ``u_lo``/``u_hi`` and ``v_lo``/``v_hi`` bound the rows of
+    ``u`` and ``v`` in ``packed``, of which :func:`classify_items` reads
+    only the other endpoint's (``v``'s on side 0, ``u``'s on side 1).
+    ``slot`` indexes ``w``'s packed entry in the row of ``side``'s
+    endpoint.  Invalid lanes carry ``slot`` and ``side`` 0 and in-range
+    values elsewhere, so every gather stays in bounds; every mask drops
+    them.
+    """
+    u: jax.Array
+    v: jax.Array
+    pair_code: jax.Array
+    u_lo: jax.Array
+    u_hi: jax.Array
+    v_lo: jax.Array
+    v_hi: jax.Array
+    slot: jax.Array
+    side: jax.Array
+    valid: jax.Array
+
+
 @_stage("classify")
-def classify_items(indptr, packed, pair_u, pair_v, pair_code,
-                   item_pair, item_slot, item_side, item_valid,
-                   search_iters: int):
-    """Per-item triad classification. Returns (tricode, count_mask, inter_mask, is_mut).
+def gather_work_items(indptr, pair_u, pair_v, pair_code,
+                      item_pair, item_slot, item_side, item_valid):
+    """:class:`WorkItems` of host-emitted items: the pair fields and the
+    other endpoint's row bounds, gathered at ``item_pair``.  Only the
+    other endpoint's bounds are read, so they fill both endpoints'."""
+    u = pair_u[item_pair]
+    v = pair_v[item_pair]
+    other = jnp.where(item_side == 0, v, u)
+    lo = indptr[other]
+    hi = indptr[other + 1]
+    return WorkItems(u, v, pair_code[item_pair], lo, hi, lo, hi,
+                     item_slot, item_side, item_valid)
+
+
+@_stage("classify")
+def classify_items(packed, items: WorkItems, search_iters: int):
+    """Per-item triad classification: (tricode, count_mask, inter_mask,
+    is_mut, w).
 
     tricode is in [0, 64); count_mask marks items contributing a connected
     triad under the canonical-selection predicate; inter_mask marks items
     witnessing an element of N(u) ∩ N(v) on the pair's designated witness
-    side (bit 2 of ``pair_code``; 0 unless the plan is degree-oriented).
+    side (bit 2 of ``pair_code``; 0 unless the plan is degree-oriented);
+    w is the third vertex.  The only gathers are ``w``'s packed entry,
+    the binary search in the other endpoint's row, and its hit.
     """
+    u, v, side = items.u, items.v, items.side
     nbr_ids = packed >> 2
-    w_packed = packed[item_slot]
+    w_packed = packed[items.slot]
     w = w_packed >> 2
     c_side = w_packed & 3
 
-    u = pair_u[item_pair]
-    v = pair_v[item_pair]
-    pc = pair_code[item_pair]
-    c_uv = pc & 3
-    inter_side = (pc >> 2) & 1
+    c_uv = items.pair_code & 3
+    inter_side = (items.pair_code >> 2) & 1
 
-    other = jnp.where(item_side == 0, v, u)
-    lo = indptr[other]
-    hi = indptr[other + 1]
+    lo = jnp.where(side == 0, items.v_lo, items.u_lo)
+    hi = jnp.where(side == 0, items.v_hi, items.u_hi)
     pos = segment_searchsorted(nbr_ids, lo, hi, w, search_iters)
     hit = packed[jnp.clip(pos, 0, packed.shape[0] - 1)]
     found = (pos < hi) & ((hit >> 2) == w)
     c_other = jnp.where(found, hit & 3, 0)
 
-    c_uw = jnp.where(item_side == 0, c_side, c_other)
-    c_vw = jnp.where(item_side == 0, c_other, c_side)
+    c_uw = jnp.where(side == 0, c_side, c_other)
+    c_vw = jnp.where(side == 0, c_other, c_side)
 
     not_self = (w != u) & (w != v)
-    dedup = ~(found & (item_side == 1))      # union duplicates count once
+    dedup = ~(found & (side == 1))      # union duplicates count once
     canonical = (v < w) | ((u < w) & (w < v) & (c_uw == 0))
-    count_mask = item_valid & not_self & dedup & canonical
-    inter_mask = item_valid & not_self & found & (item_side == inter_side)
+    count_mask = items.valid & not_self & dedup & canonical
+    inter_mask = items.valid & not_self & found & (side == inter_side)
 
     tricode = c_uv * 16 + c_uw * 4 + c_vw
-    return tricode, count_mask, inter_mask, c_uv == 3
+    return tricode, count_mask, inter_mask, c_uv == 3, w
 
 
 @_stage("expand")
-def expand_work_items(indptr, pair_u, pair_v, desc_pair, desc_cum,
-                      desc_within0, anchors, num_valid, idx,
-                      desc_iters: int):
-    """Map flat item indices back to ``(pair, slot, side, valid)`` from a
+def expand_work_items(indptr, pair_u, pair_v, pair_code, desc_pair,
+                      desc_cum, desc_within0, anchors, num_valid, idx,
+                      desc_iters: int) -> WorkItems:
+    """Map flat item indices back to their :class:`WorkItems` from a
     per-pair descriptor window — the device-resident inverse of the host
-    planner's ``emit_items``.
+    planner's ``emit_items``.  Each pair field and row bound a lane needs
+    is gathered here, once, at the lane's pair.
 
     ``desc_cum`` is the window-local cumulative-offset table (padded with
     :data:`repro.core.planner.DESC_CUM_PAD`, which is larger than any
@@ -169,8 +209,8 @@ def expand_work_items(indptr, pair_u, pair_v, desc_pair, desc_cum,
     iterations are harmless (the converged lower bound is a fixed point
     of the search body, and the result is clamped into the anchored
     range).
-    ``num_valid`` is a traced scalar: lanes past it are padding and come
-    out clamped to safe (pair 0, slot 0) coordinates.
+    ``num_valid`` is a traced scalar: lanes past it are padding and keep
+    the clamped descriptor's pair with slot and side 0.
     """
     from repro.core.planner import DESC_ANCHOR_STRIDE
     num_descs = desc_cum.shape[0]
@@ -184,37 +224,38 @@ def expand_work_items(indptr, pair_u, pair_v, desc_pair, desc_cum,
     within = desc_within0[d] + idx - desc_cum[d]
     u = pair_u[pair]
     v = pair_v[pair]
-    row_u = indptr[u]
-    deg_u = indptr[u + 1] - row_u
+    u_lo = indptr[u]
+    u_hi = indptr[u + 1]
+    v_lo = indptr[v]
+    v_hi = indptr[v + 1]
+    deg_u = u_hi - u_lo
     side = (within >= deg_u).astype(jnp.int32)
-    slot = jnp.where(side == 0, row_u + within, indptr[v] + within - deg_u)
+    slot = jnp.where(side == 0, u_lo + within, v_lo + within - deg_u)
     valid = idx < num_valid
-    return (jnp.where(valid, pair, 0), jnp.where(valid, slot, 0),
-            jnp.where(valid, side, 0), valid)
+    return WorkItems(u, v, pair_code[pair], u_lo, u_hi, v_lo, v_hi,
+                     jnp.where(valid, slot, 0), jnp.where(valid, side, 0),
+                     valid)
 
 
 @_stage("keep")
-def prune_keep_mask(packed, pair_u, pair_v, pair_code,
-                    item_pair, item_slot, item_side, item_valid,
-                    orient: str, prune_self: bool):
+def prune_keep_mask(w, items: WorkItems, orient: str, prune_self: bool):
     """Device-side mirror of the planner's plan-time pruning predicate
     (:func:`repro.core.planner.prune_items`): which expanded items a host
-    plan would have shipped.  Pruned items already contribute zero to
-    every census counter (their count/inter masks are provably false), so
-    this mask only feeds the valid-item statistics — dropping it can never
+    plan would have shipped, from the third vertex ``w`` that
+    :func:`classify_items` returns and the lanes' pair fields — no
+    gathers of its own.  Pruned items already contribute zero to every
+    census counter (their count/inter masks are provably false), so this
+    mask only feeds the valid-item statistics — dropping it can never
     change a census."""
-    w_ids = packed[item_slot] >> 2
-    u_of = pair_u[item_pair]
-    v_of = pair_v[item_pair]
-    not_self = (w_ids != u_of) & (w_ids != v_of)
+    u, v, side = items.u, items.v, items.side
+    not_self = (w != u) & (w != v)
     if orient == "degree":
-        inter_side = (pair_code[item_pair] >> 2) & 1
-        can_count = jnp.where(item_side == 0, w_ids > v_of, w_ids > u_of)
-        return item_valid & not_self & (
-            (item_side == inter_side) | can_count)
+        inter_side = (items.pair_code >> 2) & 1
+        can_count = jnp.where(side == 0, w > v, w > u)
+        return items.valid & not_self & ((side == inter_side) | can_count)
     if prune_self:
-        return item_valid & not_self
-    return item_valid
+        return items.valid & not_self
+    return items.valid
 
 
 @_stage("reduce")
@@ -245,9 +286,10 @@ def census_partials(indptr, packed, pair_u, pair_v, pair_code,
     item_side = item_sp & 1
     item_pair = item_pv >> 1
     item_valid = (item_pv & 1) == 1
-    tricode, count_mask, inter_mask, is_mut = classify_items(
-        indptr, packed, pair_u, pair_v, pair_code,
-        item_pair, item_slot, item_side, item_valid, search_iters)
+    items = gather_work_items(indptr, pair_u, pair_v, pair_code,
+                              item_pair, item_slot, item_side, item_valid)
+    tricode, count_mask, inter_mask, is_mut, _ = classify_items(
+        packed, items, search_iters)
     return _partials_reduce(tricode, count_mask, inter_mask, is_mut,
                             histogram_fn)
 
@@ -266,15 +308,12 @@ def census_partials_desc(indptr, packed, pair_u, pair_v, pair_code,
     predicate would have kept (:func:`prune_keep_mask`) so the engine's
     valid-item statistics stay comparable with host emission.
     """
-    item_pair, item_slot, item_side, item_valid = expand_work_items(
-        indptr, pair_u, pair_v, desc_pair, desc_cum, desc_within0,
-        anchors, num_valid, idx, desc_iters)
-    tricode, count_mask, inter_mask, is_mut = classify_items(
-        indptr, packed, pair_u, pair_v, pair_code,
-        item_pair, item_slot, item_side, item_valid, search_iters)
-    keep = prune_keep_mask(packed, pair_u, pair_v, pair_code,
-                           item_pair, item_slot, item_side, item_valid,
-                           orient, prune_self)
+    items = expand_work_items(indptr, pair_u, pair_v, pair_code, desc_pair,
+                              desc_cum, desc_within0, anchors, num_valid,
+                              idx, desc_iters)
+    tricode, count_mask, inter_mask, is_mut, w = classify_items(
+        packed, items, search_iters)
+    keep = prune_keep_mask(w, items, orient, prune_self)
     return _partials_reduce(tricode, count_mask, inter_mask, is_mut,
                             histogram_fn, keep_mask=keep)
 

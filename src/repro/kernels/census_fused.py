@@ -71,7 +71,7 @@ def _accumulate_block(out_ref, tricode, count_mask, inter_mask, is_mut,
 def _kernel(ip_ref, pk_ref, pu_ref, pv_ref, pc_ref, sp_ref, pw_ref,
             out_ref, *, search_iters: int):
     # lazy import: repro.core.census lazily imports this package in turn
-    from repro.core.census import classify_items
+    from repro.core.census import classify_items, gather_work_items
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -94,8 +94,9 @@ def _kernel(ip_ref, pk_ref, pu_ref, pv_ref, pc_ref, sp_ref, pw_ref,
 
     # gather + unrolled binary search + classification: the same pure-jnp
     # implementation as the oracle backend, traced on VMEM-resident values
-    tricode, count_mask, inter_mask, is_mut = classify_items(
-        ip, pk, pu, pvv, pc, pair, slot, side, valid, search_iters)
+    items = gather_work_items(ip, pu, pvv, pc, pair, slot, side, valid)
+    tricode, count_mask, inter_mask, is_mut, _ = classify_items(
+        pk, items, search_iters)
     _accumulate_block(out_ref, tricode, count_mask, inter_mask, is_mut)
 
 
@@ -104,7 +105,7 @@ def _desc_kernel(ip_ref, pk_ref, pu_ref, pv_ref, pc_ref, dp_ref, dc_ref,
                  num_descs: int, num_anchors: int, search_iters: int,
                  desc_iters: int, orient: str, prune_self: bool):
     """Device-emission variant: the item block arrives as flat *indices*
-    only; the kernel expands each index to its (pair, slot, side) from the
+    only; the kernel expands each index to its work item from the
     VMEM-resident descriptor window before classifying — work items never
     exist on the host or in HBM at all."""
     from repro.core.census import (
@@ -129,12 +130,11 @@ def _desc_kernel(ip_ref, pk_ref, pu_ref, pv_ref, pc_ref, dp_ref, dc_ref,
     nv = nv_ref[...].reshape(-1)[0]
     idx = idx_ref[...].reshape(-1)
 
-    pair, slot, side, valid = expand_work_items(
-        ip, pu, pvv, dp, dc, dw, an, nv, idx, desc_iters)
-    tricode, count_mask, inter_mask, is_mut = classify_items(
-        ip, pk, pu, pvv, pc, pair, slot, side, valid, search_iters)
-    keep = prune_keep_mask(pk, pu, pvv, pc, pair, slot, side, valid,
-                           orient, prune_self)
+    items = expand_work_items(ip, pu, pvv, pc, dp, dc, dw, an, nv, idx,
+                              desc_iters)
+    tricode, count_mask, inter_mask, is_mut, w = classify_items(
+        pk, items, search_iters)
+    keep = prune_keep_mask(w, items, orient, prune_self)
     _accumulate_block(out_ref, tricode, count_mask, inter_mask, is_mut,
                       keep_mask=keep)
 

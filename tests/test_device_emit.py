@@ -9,9 +9,11 @@ three execution paths (full runs, streamed chunks, incremental updates),
 while shipping far fewer host→device plan bytes.
 """
 
+import jax
 import numpy as np
 import pytest
 
+from repro.core import census
 from repro.core import (
     CensusEngine, PlanChunker, apply_delta, census_batagelj_mrvar,
     default_mesh, descriptor_window, from_edges, iter_descriptor_windows,
@@ -32,6 +34,12 @@ def hub_graph(n=24, hub_out=16, extra=40, seed=0):
 def expand_window_np(space, win):
     """Numpy reference of the device expansion (including the anchored
     search bound), returning the window's PRUNED (pair, slot, side)."""
+    return prune_items(space, *preprune_window_np(space, win))
+
+
+def preprune_window_np(space, win):
+    """The window's pre-prune ``(pair, slot, side)`` lanes, in numpy,
+    asserting the anchored search bound on the way."""
     nd = win.num_descs
     cum = win.desc_cum[:nd].astype(np.int64)
     idx = np.arange(win.num_preprune, dtype=np.int64)
@@ -48,7 +56,7 @@ def expand_window_np(space, win):
     side = (within >= deg_u).astype(np.int8)
     slot = np.where(side == 0, space.indptr[u] + within,
                     space.indptr[space.pair_v[pair]] + within - deg_u)
-    return prune_items(space, pair, slot, side)
+    return pair, slot, side
 
 
 # --------------------------------------------------------- descriptors
@@ -135,6 +143,91 @@ class TestDescriptorWindows:
         win = descriptor_window(space.offsets, 5, 5, 4,
                                 num_desc_anchors(16))
         assert win.num_descs == 0 and win.num_preprune == 0
+
+
+# ------------------------------------------------------ stage contract
+
+
+class TestStageContract:
+    """``expand`` gathers each pair field and row bound a lane needs
+    once; ``classify`` and ``keep`` read them from its output and agree,
+    lane by lane, with the same items emitted on the host."""
+
+    @pytest.mark.parametrize("orient", ["none", "degree"])
+    def test_expand_feeds_classify_what_host_items_give(self, orient):
+        g = hub_graph(n=40, hub_out=24, extra=160, seed=7)
+        ck = PlanChunker(g, max_items=29, orient=orient)
+        sp = ck.space
+        lanes = ck.chunk_shape
+        # the last window is partly padding
+        assert 0 < sp.num_items_preprune % lanes
+        tables = ck.device_arrays()
+        indptr, packed, pair_u, pair_v, pair_code = tables
+        idx = np.arange(lanes, dtype=np.int32)
+        nd = ck.desc_shape
+
+        @jax.jit
+        def stages(tables, words, h_pair, h_slot, h_side, h_valid):
+            ip, pk, pu, pv, pc = tables
+            items = census.expand_work_items(
+                ip, pu, pv, pc, words[1:1 + nd],
+                words[1 + nd:1 + 2 * nd], words[1 + 2 * nd:1 + 3 * nd],
+                words[1 + 3 * nd:], words[:1], idx, ck.desc_iters)
+            host = census.gather_work_items(ip, pu, pv, pc, h_pair,
+                                            h_slot, h_side, h_valid)
+            dev = census.classify_items(pk, items, sp.search_iters)
+            ref = census.classify_items(pk, host, sp.search_iters)
+            keep = census.prune_keep_mask(dev[4], items, sp.orient,
+                                          sp.prune_self)
+            return items, dev, ref, keep
+
+        windows_of = {}                      # pair -> windows holding it
+        for k in range(ck.num_chunks):
+            win = ck.descriptors(k)
+            nv = win.num_preprune
+            pair, slot, side = preprune_window_np(sp, win)
+            for p in np.unique(pair):
+                windows_of.setdefault(int(p), set()).add(k)
+            valid = idx < nv
+            host = [np.zeros(lanes, np.int32) for _ in range(3)]
+            for h, a in zip(host, (pair, slot, side)):
+                h[:nv] = a
+            items, dev, ref, keep = jax.device_get(
+                stages(tables, win.device_words(), *host, valid))
+
+            # valid lanes: the tables' entries at the lane's pair
+            u, v = pair_u[pair], pair_v[pair]
+            np.testing.assert_array_equal(items.valid, valid)
+            np.testing.assert_array_equal(items.u[:nv], u)
+            np.testing.assert_array_equal(items.v[:nv], v)
+            np.testing.assert_array_equal(items.pair_code[:nv],
+                                          pair_code[pair])
+            np.testing.assert_array_equal(items.u_lo[:nv], indptr[u])
+            np.testing.assert_array_equal(items.u_hi[:nv], indptr[u + 1])
+            np.testing.assert_array_equal(items.v_lo[:nv], indptr[v])
+            np.testing.assert_array_equal(items.v_hi[:nv], indptr[v + 1])
+            np.testing.assert_array_equal(items.slot[:nv], slot)
+            np.testing.assert_array_equal(items.side[:nv], side)
+            # padded lanes: slot and side 0, every field in range
+            assert not items.slot[nv:].any() and not items.side[nv:].any()
+            for a in (items.u[nv:], items.v[nv:]):
+                assert ((0 <= a) & (a < sp.n)).all()
+            for a in items[3:7]:
+                assert ((0 <= a[nv:]) & (a[nv:] <= packed.shape[0])).all()
+
+            # classify: the same (tricode, count, inter, is_mut) per lane
+            for got, want in zip(dev[:4], ref[:4]):
+                np.testing.assert_array_equal(got[:nv], want[:nv])
+            np.testing.assert_array_equal(dev[4][:nv], sp.nbr[slot])
+            for mask in (dev[1], dev[2], ref[1], ref[2], keep):
+                assert not mask[nv:].any()
+            # keep: the lanes it keeps are the host plan's items
+            kept = keep[:nv]
+            for got, want in zip((pair[kept], slot[kept], side[kept]),
+                                 prune_items(sp, pair, slot, side)):
+                np.testing.assert_array_equal(got, want)
+        assert any(len(ks) >= 2 for ks in windows_of.values()), \
+            "no pair split across windows"
 
 
 # ------------------------------------------------------------- engines

@@ -15,6 +15,7 @@ this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +46,15 @@ K = eng.MAX_WINDOWS_PER_DISPATCH
 HBM_BYTES = 16 * 10**9
 
 DESC_WORDS = 1 + 3 * DESC_SHAPE + num_desc_anchors(CHUNK)
+
+#: shapes of the benchmark's ``patents-batch`` cell (the 1/16
+#: cit-Patents graph: 235,923 vertices, 1,032,434 pairs, 177,683
+#: descriptors a window) in the same 4,194,304-lane chunks
+BATCH_N_INDPTR = 235_924
+BATCH_N_PACKED = 2_064_868
+BATCH_N_PAIRS = 1_032_434
+BATCH_DESC_SHAPE = 177_683
+BATCH_SEARCH_ITERS = 10
 
 
 @pytest.fixture(scope="module")
@@ -101,6 +111,60 @@ def test_desc_step_compiles(graph, i32):
         *graph, i32(DESC_WORDS), i32(CHUNK), None, SEARCH_ITERS,
         DESC_SEARCH_ITERS, "jnp", "degree", True).compile()
     _fits_one_chip(compiled)
+
+
+#: one instruction of compiled HLO text: name, opcode, operands
+_INSTR = re.compile(r"^\s+(?:ROOT )?%(\S+) = .*? ([a-z][\w-]*)\(([^)]*)\)")
+
+
+def _gather_tables(hlo: str, lanes: int) -> list[str]:
+    """The table each ``lanes``-wide gather of a compiled module reads:
+    the entry parameter it comes from, through fusion parameters and
+    copies, else the instruction that makes it."""
+    defs, caller, gathers, comp = {}, {}, [], None
+    for line in hlo.splitlines():
+        if not line.startswith(" "):
+            comp = re.match(r"(?:ENTRY )?%([\w.-]+)", line)
+            comp = comp and comp.group(1)
+            continue
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name, op, args = m.groups()
+        defs[name] = (comp, op, re.findall(r"%([\w.-]+)", args) or args)
+        called = re.search(r"calls=%([\w.-]+)", line)
+        if called:
+            caller[called.group(1)] = name
+        if op == "gather" and f"= s32[{lanes}]{{" in line:
+            gathers.append(name)
+
+    def source(name):
+        comp, op, args = defs[name]
+        if op == "parameter" and comp in caller:
+            return source(defs[caller[comp]][2][int(args)])
+        if op in ("copy-start", "copy-done", "copy", "bitcast"):
+            return source(args[0])
+        return name
+
+    return [source(defs[g][2][0]) for g in gathers]
+
+
+def test_desc_step_gathers_each_pair_field_once(topo):
+    """At ``patents-batch``'s shapes the descriptor step reads each of
+    the pair tables once per lane: ``expand`` gathers the pair fields
+    and row bounds, and ``classify``/``keep`` take them from it."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    words = 1 + 3 * BATCH_DESC_SHAPE + num_desc_anchors(CHUNK)
+    text = eng._desc_step.lower(
+        i32(BATCH_N_INDPTR), i32(BATCH_N_PACKED), i32(BATCH_N_PAIRS),
+        i32(BATCH_N_PAIRS), i32(BATCH_N_PAIRS), i32(words), i32(CHUNK),
+        None, BATCH_SEARCH_ITERS, DESC_SEARCH_ITERS, "jnp", "degree",
+        True).compile().as_text()
+    tables = [t.split(".")[0] for t in _gather_tables(text, CHUNK)]
+    assert 0 < len(tables) <= 28, tables
+    for field in ("pair_u", "pair_v", "pair_code"):
+        assert tables.count(field) == 1, tables
 
 
 def test_megastep_compiles(graph, i32):
