@@ -457,6 +457,15 @@ class EngineStats:
     chunk_shape: int           #: padded items per dispatch
     items: int                 #: total valid work items processed
     chunk_items: list[int] = field(default_factory=list)
+    #: valid lanes dispatched before the prune mask (the pre-prune item
+    #: space, summed over shards when partitioned; under host emission
+    #: what the host pruned before upload): ``items`` over it is the
+    #: share of lanes kept, and ``chunks * chunk_shape`` less it the
+    #: padding of an un-partitioned run.  0 where not recorded
+    preprune_items: int = 0
+    #: dependent search rounds per lane in the classify step
+    #: (``ceil(log2(max_degree + 1))``).  0 where not recorded
+    search_iters: int = 0
     peak_plan_bytes: int = 0
     monolithic_plan_bytes: int = 0
     step_compiles: int = 0
@@ -1099,6 +1108,8 @@ class CensusEngine:
             streamed=True, max_items=chunker.max_items,
             chunks=chunker.num_chunks, chunk_shape=chunker.chunk_shape,
             items=0, peak_plan_bytes=ITEM_BYTES * chunker.chunk_shape,
+            preprune_items=space.num_items_preprune,
+            search_iters=space.search_iters,
             emit="host",
             # item arrays are sharded over the mesh (chunk_shape is a
             # multiple of ndev): physical per-device upload bytes
@@ -1191,6 +1202,8 @@ class CensusEngine:
             streamed=max_items is not None, max_items=max_items,
             chunks=chunker.num_chunks, chunk_shape=chunker.chunk_shape,
             items=0, peak_plan_bytes=ITEM_BYTES * chunker.chunk_shape,
+            preprune_items=space.num_items_preprune,
+            search_iters=space.search_iters,
             emit="device", desc_shape=chunker.desc_shape,
             plan_upload_bytes=upload,
             graph_resident_bytes=gbytes, graph_replicated_bytes=gbytes,
@@ -1297,9 +1310,12 @@ class CensusEngine:
                                   max_items, self.ndev,
                                   mesh_shape=getattr(part, "mesh_shape",
                                                      None))
-        host = {"host_pair_seconds": plan_seconds,
-                "host_partition_seconds": partition.seconds}
         space = part.space
+        host = {"host_pair_seconds": plan_seconds,
+                "host_partition_seconds": partition.seconds,
+                "preprune_items": sum(sh.space.num_items_preprune
+                                      for sh in part.shards),
+                "search_iters": space.search_iters}
         upload = (4 * (1 + 3 * sched.desc_shape + sched.num_anchors)
                   if emit == "device"
                   else ITEM_BYTES * sched.chunk_shape)
