@@ -258,6 +258,15 @@ def slice_pair_terms(space: PairSpace, vertex_bounds: np.ndarray
             for j in range(num_slices)]
 
 
+def _mark(n: int, *ids: np.ndarray) -> np.ndarray:
+    """(n,) bool table of the vertices in ``ids``: its ``flatnonzero`` is
+    their sorted distinct ids, with no sort."""
+    mark = np.zeros(n, dtype=bool)
+    for x in ids:
+        mark[x] = True
+    return mark
+
+
 @dataclass(frozen=True)
 class LocalShard:
     """One device's private slice of the census: the pairs it owns and the
@@ -331,8 +340,7 @@ def extract_shard(space: PairSpace, pair_ids, index: int = 0,
         if costs is None:
             costs = postprune_pair_counts(space)
         pu, pv = space.pair_u[ids], space.pair_v[ids]
-        ends = (np.unique(np.concatenate([pu, pv])) if ids.size
-                else np.zeros(0, dtype=np.int64))
+        ends = np.flatnonzero(_mark(space.n, pu, pv))
         row_start = space.indptr[ends].astype(np.int64)
         row_deg = deg[ends]
     else:
@@ -358,8 +366,7 @@ def extract_shard(space: PairSpace, pair_ids, index: int = 0,
         keep = (cnt(pu, lo_v, hi_v) + cnt(pv, lo_v, hi_v)) > 0
         ids = ids[keep]
         pu, pv = pu[keep], pv[keep]
-        ends = (np.unique(np.concatenate([pu, pv])) if ids.size
-                else np.zeros(0, dtype=np.int64))
+        ends = np.flatnonzero(_mark(space.n, pu, pv))
         below = np.searchsorted(key, ends * n64 + lo_v) - space.indptr[ends]
         row_deg = cnt(ends, lo_v, hi_v).astype(np.int64)
         row_start = (space.indptr[ends] + below).astype(np.int64)
@@ -377,14 +384,17 @@ def extract_shard(space: PairSpace, pair_ids, index: int = 0,
     rows_packed = space.packed[slot].astype(np.int64)
     nbrs = rows_packed >> 2
 
-    verts = np.union1d(ends, nbrs)
+    # order-preserving relabel through a dense [0, n) table: a vertex's
+    # local id is the number of shard vertices below it, read by gather
+    mark = _mark(space.n, ends, nbrs)
+    verts = np.flatnonzero(mark)
+    local = np.cumsum(mark, dtype=np.int32) - 1
     n_loc = int(verts.shape[0])
-    ends_loc = np.searchsorted(verts, ends)
     deg_loc = np.zeros(n_loc, dtype=np.int64)
-    deg_loc[ends_loc] = row_deg
+    deg_loc[local[ends]] = row_deg
     indptr_loc = np.zeros(n_loc + 1, dtype=np.int64)
     np.cumsum(deg_loc, out=indptr_loc[1:])
-    nbr_loc = np.searchsorted(verts, nbrs)
+    nbr_loc = local[nbrs]
     packed_loc = ((nbr_loc << 2) | (rows_packed & 3)).astype(np.int32)
     g_loc = CompactDigraph(
         n=n_loc, indptr=indptr_loc, packed=packed_loc,
@@ -395,7 +405,7 @@ def extract_shard(space: PairSpace, pair_ids, index: int = 0,
     term_src = (space.pair_term if pair_term is None
                 else np.asarray(pair_term, dtype=np.int64).ravel())
     space_loc = make_pair_space(
-        g_loc, np.searchsorted(verts, pu), np.searchsorted(verts, pv),
+        g_loc, local[pu], local[pv],
         space.pair_code[ids].copy(), orient=space.orient,
         prune_self=space.prune_self,
         pair_term=term_src[ids].copy())

@@ -20,9 +20,13 @@ import pytest
 from repro.core import (
     CensusEngine, TriadMonitor, apply_delta, census_batagelj_mrvar,
     default_mesh, extract_shard, from_edges, lpt_assign, pair_space,
-    partition_graph, replicated_graph_bytes, scale_free_digraph,
-    shard_report, to_dense, triad_census_graph)
-from repro.core.planner import emit_items_for_pairs, postprune_pair_counts
+    partition_graph, partition_graph_2d, replicated_graph_bytes,
+    scale_free_digraph, shard_report, to_dense, triad_census_graph)
+from repro.core.digraph import entry_keys
+from repro.core.partition import slice_pair_terms
+from repro.core.planner import (
+    INTER_SIDE_BIT, emit_items_for_pairs, postprune_pair_counts,
+    range_postprune_pair_counts)
 
 
 def pl_graph(n=100, deg=5, seed=7, mutual_p=0.3):
@@ -201,6 +205,138 @@ class TestExtractShard:
         space = pair_space(pl_graph())
         with pytest.raises(ValueError, match="pair id"):
             extract_shard(space, [space.num_pairs])
+
+    @pytest.mark.parametrize("case", [
+        "power-law", "power-law-mutual", "hub", "empty-shard", "one-pair",
+        "2d-tiles"])
+    def test_relabel_matches_sorted_search_form(self, case):
+        """Every array of a shard equals, value and dtype, what the
+        relabel by ``np.union1d`` and ``np.searchsorted`` gave."""
+        for space, sh, costs, term in shard_cases(case):
+            want = reference_shard(space, sh.pair_ids, costs,
+                                   sh.vertex_range, term)
+            got = {"pair_ids": sh.pair_ids, "verts": sh.verts,
+                   "keys": sh.keys, "indptr": sh.graph.indptr,
+                   "packed": sh.graph.packed,
+                   "pair_u": sh.space.pair_u, "pair_v": sh.space.pair_v,
+                   "pair_code": sh.space.pair_code,
+                   "pair_term": sh.space.pair_term}
+            assert sh.items == want.pop("items")
+            for name, arr in want.items():
+                assert got[name].dtype == arr.dtype, name
+                np.testing.assert_array_equal(got[name], arr, err_msg=name)
+
+
+def shard_cases(case):
+    """(global space, shard, the costs it was cut with, its pair terms or
+    None) for each shard of one relabel case."""
+    if case == "2d-tiles":
+        space = pair_space(pl_graph(n=90, seed=4), orient="degree")
+        part = partition_graph_2d(space=space, mesh_shape=(2, 3))
+        terms = slice_pair_terms(space, part.vertex_bounds)
+        for sh in part.shards:
+            lo, hi = sh.vertex_range
+            j = sh.index % part.num_vertex_slices
+            yield (space, sh, range_postprune_pair_counts(space, lo, hi),
+                   terms[j])
+        return
+    g = {"power-law": lambda: pl_graph(seed=12, mutual_p=0.0),
+         "power-law-mutual": lambda: pl_graph(seed=12, mutual_p=0.3),
+         "hub": hub_graph,
+         "empty-shard": lambda: from_edges([0, 1], [1, 2], n=5),
+         "one-pair": lambda: pl_graph(n=30, seed=2)}[case]()
+    space = pair_space(g, orient="degree")
+    costs = postprune_pair_counts(space)
+    if case == "one-pair":
+        shards = [extract_shard(space, [space.num_pairs // 2], costs=costs)]
+    else:
+        ns = 8 if case == "empty-shard" else 3
+        shards = partition_graph(space=space, num_shards=ns).shards
+    if case == "empty-shard":
+        assert any(sh.num_pairs == 0 for sh in shards)
+    for sh in shards:
+        yield space, sh, costs, None
+
+
+def reference_shard(space, pair_ids, costs, vertex_range=None,
+                    pair_term=None):
+    """A shard's arrays by the relabel's sorted-search form: the local
+    vertices are ``np.union1d`` of the endpoints and their (in-range)
+    neighbours, and every id is relabelled by ``np.searchsorted``."""
+    lo, hi = vertex_range if vertex_range is not None else (0, space.n)
+
+    def row(x):
+        e = space.packed[space.indptr[x]:space.indptr[x + 1]].astype(
+            np.int64)
+        return e[((e >> 2) >= lo) & ((e >> 2) < hi)]
+
+    ids = np.sort(np.asarray(pair_ids, dtype=np.int64))
+    pu, pv = space.pair_u[ids], space.pair_v[ids]
+    ends = np.unique(np.concatenate([pu, pv]))
+    rows = [row(x) for x in ends]
+    rows_packed = np.concatenate(rows + [np.zeros(0, np.int64)])
+    nbrs = rows_packed >> 2
+    verts = np.union1d(ends, nbrs)
+    deg = np.zeros(verts.shape[0], dtype=np.int64)
+    deg[np.searchsorted(verts, ends)] = [r.shape[0] for r in rows]
+    indptr = np.zeros(verts.shape[0] + 1, dtype=np.int64)
+    np.cumsum(deg, out=indptr[1:])
+    term = space.pair_term if pair_term is None else pair_term
+    return {"pair_ids": ids, "verts": verts, "keys": pu * space.n + pv,
+            "indptr": indptr,
+            "packed": ((np.searchsorted(verts, nbrs) << 2)
+                       | (rows_packed & 3)).astype(np.int32),
+            "pair_u": np.searchsorted(verts, pu),
+            "pair_v": np.searchsorted(verts, pv),
+            "pair_code": space.pair_code[ids], "pair_term": term[ids],
+            "items": int(costs[ids].sum())}
+
+
+# ------------------------------------------------------------ cost vector
+
+
+def unsorted_search_counts(space, pair_ids=None):
+    """:func:`postprune_pair_counts` by its closed form with both entry-key
+    searches made in the pairs' own order."""
+    ids = (np.arange(space.num_pairs) if pair_ids is None
+           else np.asarray(pair_ids))
+    pu, pv = space.pair_u[ids], space.pair_v[ids]
+    counts = space.counts[ids]
+    if space.orient != "degree":
+        return counts - (2 if space.prune_self else 0)
+    key = np.repeat(np.arange(space.n), space.deg) * space.n + space.nbr
+    pos_v = np.searchsorted(key, pu * space.n + pv) - space.indptr[pu]
+    pos_u = np.searchsorted(key, pv * space.n + pu) - space.indptr[pv]
+    du, dv = space.deg[pu], space.deg[pv]
+    inter = (space.pair_code[ids] >> INTER_SIDE_BIT) & 1
+    return (np.where(inter == 0, du - 1, du - pos_v - 1)
+            + np.where(inter == 1, dv - 1, dv - pos_u - 1))
+
+
+class TestPostpruneCounts:
+    @pytest.mark.parametrize("scope", ["full", "shuffled-subset",
+                                       "shard-local"])
+    @pytest.mark.parametrize("orient", ["none", "degree"])
+    def test_matches_unsorted_search_and_emission(self, orient, scope):
+        """The cost vector equals its unsorted-search form and the
+        per-pair count of emitted items, in every kind of pair space."""
+        g = pl_graph(n=150, deg=6, seed=19)
+        space = pair_space(g, orient=orient)
+        ids, kw = None, {}
+        if scope == "shuffled-subset":
+            rng = np.random.default_rng(19)
+            ids = rng.permutation(space.num_pairs)[:space.num_pairs // 3]
+            kw = {"entry_key": entry_keys(g)}
+        elif scope == "shard-local":
+            space = partition_graph(space=space, num_shards=3).shards[1].space
+        got = postprune_pair_counts(space, ids, **kw)
+        np.testing.assert_array_equal(got, unsorted_search_counts(space, ids))
+        want_ids = np.arange(space.num_pairs) if ids is None else ids
+        emitted = np.bincount(emit_items_for_pairs(space, want_ids)[0],
+                              minlength=space.num_pairs)[want_ids]
+        np.testing.assert_array_equal(got, emitted)
+        assert space.num_items_postprune() == emit_items_for_pairs(
+            space, np.arange(space.num_pairs))[0].shape[0]
 
 
 # ------------------------------------------------- shard-count invariance
