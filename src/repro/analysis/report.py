@@ -189,7 +189,6 @@ def main():
         "",
         "Artifacts: `experiments/dryrun/*.json` (one per cell), "
         "`experiments/variants/*.json` (§Perf iterations), "
-        "`benchmarks/run.py` CSV (`bench_output.txt`), "
         "`tests/` (`test_output.txt`). Hardware target: TPU v5e pods "
         "(16×16 per pod); host: 1-core CPU container (compile-only "
         "dry-runs, interpret-mode kernels).",

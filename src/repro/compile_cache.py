@@ -1,8 +1,8 @@
 """Where the entry points keep JAX's persistent compilation cache.
 
 Importing the library sets nothing; the scripts (``chip_smoke.py``,
-``benchmarks/run.py``, ``examples/*``) call :func:`enable_compile_cache`
-once at start-up.
+``benchmarks/chip/run.py``, ``examples/*``) call
+:func:`enable_compile_cache` once at start-up.
 """
 
 from __future__ import annotations

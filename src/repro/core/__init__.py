@@ -24,11 +24,8 @@ Public API::
     engine = CensusEngine(mesh, partition=True)
     census = engine.run(g)            # bit-identical, private shards
     session = engine.session(g)       # deltas dispatch owning shards only
-
-    # partitioned runs drain per-shard streams asynchronously (no
-    # inter-shard barrier; walltime tracks the MEAN shard, not the max);
-    # schedule="lockstep" keeps the collective barrier as the oracle
-    census = engine.run(g, schedule="lockstep")
+    # each shard drains its own stream: no inter-shard barrier, so
+    # walltime tracks the MEAN shard, not the max
 
     # 2D pair×vertex: keep the LPT pair axis, slice each shard's
     # witness range across V vertex slices — the adjacency halo shards
@@ -55,7 +52,7 @@ from repro.core.census import (
     triad_census, assemble_census, census_partials_desc_batch,
     check_backend)
 from repro.core.engine import (
-    CensusEngine, EMIT_MODES, SCHEDULES, EngineSession, EngineStats,
+    CensusEngine, EMIT_MODES, EngineSession, EngineStats,
     PartitionedEngineSession, PartitionedEngineSession2D, StepCompileError)
 from repro.core.incremental import (
     affected_pair_ids, subset_contribution, subset_descriptor_windows,
@@ -89,7 +86,7 @@ __all__ = [
     "ShardStreamPipeline", "WindowBatcher", "iter_plan_chunks",
     "Fault", "FaultError", "FaultInjector", "FaultPlan", "InjectedFault",
     "PlanOverflowError",
-    "CensusEngine", "EMIT_MODES", "SCHEDULES", "EngineSession",
+    "CensusEngine", "EMIT_MODES", "EngineSession",
     "EngineStats", "PartitionedEngineSession",
     "PartitionedEngineSession2D", "StepCompileError",
     "affected_pair_ids", "subset_contribution",

@@ -136,8 +136,8 @@ def triad_census_graph(g: CompactDigraph, mesh: Mesh | None = None,
                        progress=None,
                        emit: str | None = None,
                        partition: bool = False,
-                       partition_2d: tuple[int, int] | None = None,
-                       schedule: str = "async") -> np.ndarray:
+                       partition_2d: tuple[int, int] | None = None
+                       ) -> np.ndarray:
     """Convenience: plan + distribute + count in one call.
 
     ``max_items=None`` reproduces the historical one-dispatch schedule;
@@ -146,9 +146,7 @@ def triad_census_graph(g: CompactDigraph, mesh: Mesh | None = None,
     upload + in-kernel pair→item expansion; ``"host"``: packed-item
     upload).  ``partition=True`` shards the GRAPH across the mesh — each
     device holds only its pair shard's local subgraph and walks its own
-    stream (:mod:`repro.core.partition`); ``schedule`` then picks the
-    execution discipline (``"async"``: private per-shard streams, no
-    inter-shard barrier; ``"lockstep"``: the collective oracle).
+    stream (:mod:`repro.core.partition`) with no inter-shard barrier.
     ``partition_2d=(P, V)`` upgrades the partitioned path to the 2D
     pair×vertex decomposition — ``P·V`` must equal the mesh's device
     count — sharding each pair shard's adjacency halo across ``V``
@@ -158,7 +156,6 @@ def triad_census_graph(g: CompactDigraph, mesh: Mesh | None = None,
     if mesh is None:
         mesh = default_mesh()
     engine = CensusEngine(mesh=mesh, backend=backend,
-                          partition=partition, partition_2d=partition_2d,
-                          schedule=schedule)
+                          partition=partition, partition_2d=partition_2d)
     return engine.run(g, max_items=max_items, orient=orient,
                       progress=progress, emit=emit)

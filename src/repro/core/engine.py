@@ -13,10 +13,9 @@ adds the out-of-core mode that the monolithic drivers could not express:
   space into bounded chunks; the engine uploads the chunk-invariant graph
   and pair arrays once, runs one jitted fixed-shape partials step per
   chunk (every chunk is padded to the same ``chunk_shape``, so the step
-  compiles exactly once; item buffers are donated for HBM reuse), overlaps
-  the host-side generation + upload of chunk k+1 with the device compute
-  of chunk k, and accumulates the ``hist64``/``inter`` partials in int64
-  on the host.  Peak plan memory is O(max_items) instead of O(W).
+  compiles exactly once), overlaps the host-side generation + upload of
+  chunk k+1 with the device compute of chunk k, and accumulates the
+  ``hist64``/``inter`` partials in int64 on the host.  Peak plan memory is O(max_items) instead of O(W).
 
 Orthogonally, ``emit`` picks how chunks reach the device:
 
@@ -55,11 +54,11 @@ instead of replicating it (:mod:`repro.core.partition`): the pair space
 is LPT-split into one private shard per mesh device, each device holds
 only its shard's order-preservingly relabeled local subgraph
 (O(E_shard + halo) resident bytes instead of O(E)) and walks its own
-descriptor/item stream — through the partitioned collective steps for
-full runs (`_part_chunk_step` / `_part_desc_step`: graph arrays are
-sharded inputs with a leading device axis, one closing psum) and through
-per-device committed dispatches for :class:`PartitionedEngineSession`,
-whose delta updates touch only the shards owning affected pairs.
+descriptor/item stream as single-device dispatches against its own
+committed shard buffers, with no inter-shard barrier: the host merges
+the per-window partials in int64.  Full runs and
+:class:`PartitionedEngineSession` share that discipline; a session's
+delta updates touch only the shards owning affected pairs.
 """
 
 from __future__ import annotations
@@ -109,14 +108,6 @@ from repro.core.spans import span
 #: oracle and for prebuilt monolithic plans)
 EMIT_MODES = ("device", "host")
 
-#: partitioned execution disciplines: ``async`` (the default) walks each
-#: shard's private chunk queue independently — per-device dispatches, no
-#: inter-shard barrier, background per-shard window producers — so
-#: walltime tracks the MEAN shard cost; ``lockstep`` advances every
-#: shard's queue together through one collective dispatch per step (the
-#: slowest shard gates each step) and is kept as the bit-identity oracle
-SCHEDULES = ("async", "lockstep")
-
 #: per-shard produced-window queue depth of the async host pipeline
 #: (2 == double-buffering: one window in flight, one pre-built behind it)
 PIPELINE_DEPTH = 2
@@ -162,13 +153,7 @@ def _chunk_step_impl(indptr, packed, pair_u, pair_v, pair_code,
 
 
 _STATIC = ("mesh", "search_iters", "backend")
-#: donated variant: each chunk's packed item buffers hand their HBM to the
-#: next upload (accelerators only — XLA:CPU cannot alias donated inputs,
-#: so the plain variant avoids a per-chunk "unusable donation" warning)
-_chunk_step_donated = functools.partial(
-    jax.jit, static_argnames=_STATIC,
-    donate_argnames=("item_sp", "item_pv"))(_chunk_step_impl)
-_chunk_step_plain = functools.partial(
+_chunk_step = functools.partial(
     jax.jit, static_argnames=_STATIC)(_chunk_step_impl)
 
 
@@ -177,12 +162,6 @@ def _platform(mesh=None) -> str:
     sharded, the default backend when single-device."""
     return (mesh.devices.flat[0].platform if mesh is not None
             else jax.default_backend())
-
-
-def _chunk_step(mesh=None):
-    """The per-chunk jitted step for the platform the work runs on."""
-    return (_chunk_step_plain if _platform(mesh) == "cpu"
-            else _chunk_step_donated)
 
 
 def _desc_step_impl(indptr, packed, pair_u, pair_v, pair_code,
@@ -254,94 +233,10 @@ def _desc_megastep_impl(indptr, packed, pair_u, pair_v, pair_code,
         search_iters, desc_iters, orient, prune_self, backend=backend)
 
 
-_MEGA_STATIC = ("search_iters", "desc_iters", "backend", "orient",
-                "prune_self")
-_desc_megastep_donated = functools.partial(
-    jax.jit, static_argnames=_MEGA_STATIC,
-    donate_argnames=("words_batch",))(_desc_megastep_impl)
-_desc_megastep_plain = functools.partial(
-    jax.jit, static_argnames=_MEGA_STATIC)(_desc_megastep_impl)
-
-
-def _desc_megastep(mesh=None):
-    """The async megastep for the platform the work runs on: the window
-    ring buffers are donated on accelerators (each upload's HBM is
-    reused by the next double-buffered batch), plain on CPU (no
-    donation support)."""
-    return (_desc_megastep_plain if _platform(mesh) == "cpu"
-            else _desc_megastep_donated)
-
-
-def _part_chunk_step_impl(indptr, packed, pair_u, pair_v, pair_code,
-                          item_sp, item_pv, mesh, search_iters, backend):
-    """Partitioned twin of :func:`_chunk_step_impl`: every array carries a
-    leading device axis and is SHARDED over the mesh — each device
-    consumes its own local-CSR row and its own packed item window (graph
-    arrays are sharded inputs, not replicated closures) — and the private
-    histograms meet in the single closing psum.
-    """
-    partials = partials_fn(backend, search_iters)
-    axes = mesh.axis_names
-
-    def shard_fn(ip, pk, pu, pv, pc, wsp, wpv):
-        hist64, inter = partials(
-            ip.reshape(-1), pk.reshape(-1), pu.reshape(-1),
-            pv.reshape(-1), pc.reshape(-1), wsp.reshape(-1),
-            wpv.reshape(-1))
-        return jax.lax.psum(hist64, axes), jax.lax.psum(inter, axes)
-
-    sh = P(axes)
-    fn = shard_map(
-        shard_fn, mesh=mesh, in_specs=(sh,) * 7, out_specs=(P(), P()),
-        check_vma=(backend == "jnp"))
-    return fn(indptr, packed, pair_u, pair_v, pair_code, item_sp, item_pv)
-
-
-_part_chunk_step = functools.partial(
-    jax.jit, static_argnames=_STATIC)(_part_chunk_step_impl)
-
-
-def _part_desc_step_impl(indptr, packed, pair_u, pair_v, pair_code,
-                         desc_words, idx, mesh, search_iters, desc_iters,
-                         backend, orient, prune_self):
-    """Partitioned twin of :func:`_desc_step_impl`: per-device descriptor
-    windows against per-device local-CSR buffers.  Every graph/pair/word
-    array is (ndev, ·) sharded over the mesh — each device expands and
-    classifies ITS OWN window of its own shard's stream — while the flat
-    item-index array stays replicated (every device walks lanes
-    ``[0, chunk_shape)`` of its private window).  One psum merges the
-    private histograms.
-    """
-    num_anchors = num_desc_anchors(idx.shape[0])
-    num_descs = (desc_words.shape[1] - 1 - num_anchors) // 3
-    partials = desc_partials_fn(backend, search_iters, desc_iters,
-                                orient, prune_self)
-    axes = mesh.axis_names
-
-    def shard_fn(ip, pk, pu, pv, pc, words, ix):
-        words = words.reshape(-1)
-        nv = words[:1]
-        dp = words[1:1 + num_descs]
-        dc = words[1 + num_descs:1 + 2 * num_descs]
-        dw = words[1 + 2 * num_descs:1 + 3 * num_descs]
-        an = words[1 + 3 * num_descs:]
-        hist64, inter = partials(
-            ip.reshape(-1), pk.reshape(-1), pu.reshape(-1),
-            pv.reshape(-1), pc.reshape(-1), dp, dc, dw, an, nv, ix)
-        return jax.lax.psum(hist64, axes), jax.lax.psum(inter, axes)
-
-    sh = P(axes)
-    fn = shard_map(
-        shard_fn, mesh=mesh,
-        in_specs=(sh, sh, sh, sh, sh, sh, P()), out_specs=(P(), P()),
-        check_vma=(backend == "jnp"))
-    return fn(indptr, packed, pair_u, pair_v, pair_code, desc_words, idx)
-
-
-_part_desc_step = functools.partial(
+_desc_megastep = functools.partial(
     jax.jit, static_argnames=(
-        "mesh", "search_iters", "desc_iters", "backend", "orient",
-        "prune_self"))(_part_desc_step_impl)
+        "search_iters", "desc_iters", "backend", "orient",
+        "prune_self"))(_desc_megastep_impl)
 
 
 def _jit_cache_size(step) -> int:
@@ -505,15 +400,8 @@ class EngineStats:
     #: to it on un-partitioned runs, ≥ it (the byte-reduction numerator)
     #: on partitioned ones
     graph_replicated_bytes: int = 0
-    #: partitioned execution discipline ("async" or "lockstep"; "" when
-    #: not partitioned)
-    schedule: str = ""
-    #: per-shard REAL dispatch steps (windows carrying pre-prune items) —
-    #: identical between schedules; what differs is ``idle_steps``
+    #: per-shard REAL dispatch steps (windows carrying pre-prune items)
     shard_steps: list[int] = field(default_factory=list)
-    #: empty padded window lanes the lock-step barrier still dispatched
-    #: (``num_steps * ndev − Σ shard_steps``); structurally 0 under async
-    idle_steps: int = 0
     #: async consumer stalls: moments every produced-window queue was
     #: empty and the host had to wait on a producer (pipeline-bound)
     stall_steps: int = 0
@@ -523,25 +411,22 @@ class EngineStats:
     #: whole run, summed across devices and dispatches
     #: (``plan_upload_bytes`` is the per-window unit).  Padding that was
     #: physically shipped but masked — megabatch rows past the real
-    #: window count under async, empty padded window lanes under
-    #: lock-step — is reported separately as ``plan_pad_bytes_total``
+    #: window count — is reported separately as ``plan_pad_bytes_total``
     #: instead of silently inflating the per-shard numbers
     plan_upload_bytes_total: int = 0
     #: masked-padding plan bytes physically shipped (see above); the
     #: run's physical upload is the sum of both totals
     plan_pad_bytes_total: int = 0
-    #: device dispatches issued for the run's windows: under the async
-    #: megastep one dispatch consumes up to ``dispatch_batch_limit``
-    #: windows, under lock-step one collective dispatch advances every
-    #: shard's lane one step
+    #: device dispatches issued for the run's windows: one megastep
+    #: dispatch consumes up to ``dispatch_batch_limit`` windows
     dispatches_total: int = 0
     #: real windows per dispatch, mean and max over the run — the
-    #: dispatch-amortization record (async megastep: adapts toward
-    #: ``dispatch_batch_limit``; lock-step: the live-lane count)
+    #: dispatch-amortization record (adapts toward
+    #: ``dispatch_batch_limit``)
     windows_per_dispatch_mean: float = 0.0
     windows_per_dispatch_max: int = 0
     #: the megabatch cap K in effect (``max_windows_per_dispatch``;
-    #: 1 == no window batching, 0 == not an async/partitioned run)
+    #: 1 == no window batching, 0 == not a partitioned run)
     dispatch_batch_limit: int = 0
     #: fault-tolerance record: window dispatches re-attempted after a
     #: transient failure (injected or real), devices retired to the
@@ -602,20 +487,17 @@ class EngineStats:
             mesh2d = (f" mesh={self.partition_shape[0]}"
                       f"x{self.partition_shape[1]}"
                       if self.partition_shape else "")
-            part = (f" partitioned[{self.schedule}]{mesh2d} "
+            part = (f" partitioned{mesh2d} "
                     f"shards={len(self.shard_items)} "
                     f"shard_max_over_mean={self.shard_max_over_mean:.3f} "
                     f"graph_bytes={self.graph_resident_bytes}"
-                    f"/{self.graph_replicated_bytes}")
-            if self.schedule == "async":
-                part += (f" stalls={self.stall_steps} "
-                         f"depth={self.pipeline_depth} "
-                         f"dispatches={self.dispatches_total} "
-                         f"win/disp={self.windows_per_dispatch_mean:.2f}"
-                         f"/{self.windows_per_dispatch_max}"
-                         f"(cap {self.dispatch_batch_limit})")
-            else:
-                part += f" idle_steps={self.idle_steps}"
+                    f"/{self.graph_replicated_bytes}"
+                    f" stalls={self.stall_steps} "
+                    f"depth={self.pipeline_depth} "
+                    f"dispatches={self.dispatches_total} "
+                    f"win/disp={self.windows_per_dispatch_mean:.2f}"
+                    f"/{self.windows_per_dispatch_max}"
+                    f"(cap {self.dispatch_batch_limit})")
         if (self.retries or self.failovers or self.watchdog_fires
                 or self.resumed_windows):
             part += (f" faults[retries={self.retries} "
@@ -745,8 +627,8 @@ class CensusEngine:
     additionally shards the GRAPH: the pair space is LPT-split into one
     private shard per mesh device (:mod:`repro.core.partition`), each
     device holds only its shard's relabeled local subgraph and walks its
-    own descriptor/item stream inside the compile-once collective step,
-    and the private histograms merge in a single psum — per-device
+    own descriptor/item stream through single-device compile-once steps,
+    and the host merges the private histograms in int64 — per-device
     resident graph bytes drop from O(E) to O(E_shard + halo), with
     bit-identical censuses.  Replication (the default) remains right for
     graphs small enough to fit every device anyway — partitioning spends
@@ -757,7 +639,6 @@ class CensusEngine:
 
     def __init__(self, mesh: Mesh | None = None, backend: str = "jnp",
                  emit: str = "device", partition: bool = False,
-                 schedule: str = "async",
                  pipeline_depth: int = PIPELINE_DEPTH,
                  max_windows_per_dispatch: int =
                  MAX_WINDOWS_PER_DISPATCH,
@@ -769,9 +650,6 @@ class CensusEngine:
         if emit not in EMIT_MODES:
             raise ValueError(
                 f"unknown emit mode {emit!r}; one of {EMIT_MODES}")
-        if schedule not in SCHEDULES:
-            raise ValueError(
-                f"unknown schedule {schedule!r}; one of {SCHEDULES}")
         if partition_2d is not None:
             partition = True          # a 2D mesh factorization implies it
             partition_2d = (int(partition_2d[0]), int(partition_2d[1]))
@@ -815,7 +693,6 @@ class CensusEngine:
         #: (pair_shards, vertex_slices) factorization of the 1-D mesh;
         #: device d serves tile (d // V, d % V).  None == 1D partition.
         self.partition_2d = partition_2d
-        self.schedule = schedule
         #: per-shard produced-window queue depth of the async host
         #: pipeline (:class:`repro.core.plan_stream.ShardStreamPipeline`)
         self.pipeline_depth = int(pipeline_depth)
@@ -890,7 +767,7 @@ class CensusEngine:
             return assemble_census(plan, np.zeros(64, np.int64),
                                    np.zeros(2, np.int64))
         rep, item_sh = self._shardings()
-        step = _chunk_step(self.mesh)
+        step = _chunk_step
         cache0 = _jit_cache_size(step)
         hist64, inter = _launch(
             step, self.backend,
@@ -907,8 +784,7 @@ class CensusEngine:
 
     def run(self, g: CompactDigraph, *, max_items: int | None = None,
             orient: str = "none", prune_self: bool = True,
-            progress=None, emit: str | None = None,
-            schedule: str | None = None, part=None,
+            progress=None, emit: str | None = None, part=None,
             checkpoint: str | None = None) -> np.ndarray:
         """Plan + count ``g`` end to end.
 
@@ -923,14 +799,12 @@ class CensusEngine:
         per chunk — at dispatch under host emission, when the chunk's
         device-counted valid items land under device emission.
 
-        Partitioned engines additionally accept ``schedule`` (default:
-        the engine's; ``"async"`` walks per-shard private queues with no
-        inter-shard barrier, ``"lockstep"`` is the collective oracle) and
-        ``part`` — a prebuilt :class:`repro.core.partition.GraphPartition`
-        over ``num_shards == ndev`` shards, overriding the internal LPT
+        Partitioned engines additionally accept ``part`` — a prebuilt
+        :class:`repro.core.partition.GraphPartition` over
+        ``num_shards == ndev`` shards, overriding the internal LPT
         (``orient``/``prune_self`` are then taken from its space).
 
-        ``checkpoint`` (partitioned async runs only) journals every
+        ``checkpoint`` (partitioned runs only) journals every
         landed window to the given JSONL path; a later ``run`` (or
         :meth:`resume`) against an existing journal restores the
         journaled partials, skips the completed windows, and reproduces
@@ -940,25 +814,20 @@ class CensusEngine:
         if emit not in EMIT_MODES:
             raise ValueError(
                 f"unknown emit mode {emit!r}; one of {EMIT_MODES}")
-        schedule = self.schedule if schedule is None else schedule
-        if schedule not in SCHEDULES:
-            raise ValueError(
-                f"unknown schedule {schedule!r}; one of {SCHEDULES}")
         if part is not None and not self.partition:
             raise ValueError(
                 "a prebuilt partition requires partition=True")
-        if checkpoint is not None and not (
-                self.partition and schedule == "async"):
+        if checkpoint is not None and not self.partition:
             raise ValueError(
-                "checkpoint/resume is supported on partitioned async "
-                "runs (partition=True, schedule='async')")
+                "checkpoint/resume is supported on partitioned runs "
+                "(partition=True)")
         census = next(self._census_ids)
         if self.partition:
             return self._run_partitioned(g, max_items=max_items,
                                          orient=orient,
                                          prune_self=prune_self,
                                          progress=progress, emit=emit,
-                                         schedule=schedule, part=part,
+                                         part=part,
                                          checkpoint=checkpoint,
                                          census=census)
         if emit == "host" and max_items is None:
@@ -978,7 +847,7 @@ class CensusEngine:
 
     def resume(self, g: CompactDigraph, checkpoint: str,
                **kwargs) -> np.ndarray:
-        """Resume a checkpointed partitioned async run: requires the
+        """Resume a checkpointed partitioned run: requires the
         journal to exist (use :meth:`run` with ``checkpoint=`` to start
         one), restores its landed windows, and completes the rest —
         bit-identical to the uninterrupted run."""
@@ -1132,7 +1001,7 @@ class CensusEngine:
         inter_acc = np.zeros(2, np.int64)
         base_asym = base_mut = 0
         chunk_items: list[int] = []
-        step = _chunk_step(self.mesh)
+        step = _chunk_step
         cache0 = _jit_cache_size(step)
         pending = None
 
@@ -1268,22 +1137,66 @@ class CensusEngine:
     def _run_partitioned(self, g: CompactDigraph, *,
                          max_items: int | None, orient: str,
                          prune_self: bool, progress, emit: str,
-                         schedule: str, census: int, part=None,
+                         census: int, part=None,
                          checkpoint: str | None = None) -> np.ndarray:
         """Partitioned plan + count: LPT-shard the pair space (or take a
         prebuilt ``part``), extract one local subgraph per mesh device,
-        and walk every device's private chunk queue
-        (:class:`repro.core.plan_stream.ShardSchedule`).  Each device
-        holds only ITS shard's relabeled CSR + pair arrays and receives
-        only its own descriptor windows (``emit="device"``) or packed
-        item windows (``emit="host"``).  ``schedule="async"`` (default)
-        drains the queues independently — per-device dispatches, host
-        merge, no inter-shard barrier; ``"lockstep"`` advances them
-        together through the collective step with a single closing psum
-        (the oracle).  Bit-identical to the replicated and single-device
-        paths for every backend, orient, emit and schedule (the
-        relabeling is order-preserving, the pair partition is exact, and
-        the partials are integer sums — merge order cannot matter)."""
+        and let every device drain its PRIVATE chunk queue
+        (:class:`repro.core.plan_stream.ShardSchedule`) with no
+        inter-shard barrier.  Each device holds only ITS shard's
+        relabeled CSR + pair arrays and receives only its own descriptor
+        windows (``emit="device"``) or packed item windows
+        (``emit="host"``), dispatched as independent single-device steps
+        against per-device-committed shard buffers — the
+        :class:`PartitionedEngineSession` dispatch discipline applied to
+        the full run.  A shard with 3 chunks is done after 3 dispatches
+        while a 12-chunk shard keeps going, so walltime tracks the MEAN
+        shard cost, not the max.
+
+        The host side is pipelined by a
+        :class:`repro.core.plan_stream.ShardStreamPipeline`: one
+        background producer per non-empty shard packs descriptor
+        windows / emits item batches ``pipeline_depth`` windows ahead
+        into its private queue, so window k+1's generation + upload
+        overlaps window k's compute (zero-window shards never get a
+        producer or a rotation slot); dispatches are async (futures)
+        with a bounded in-flight deque of ``2 * ndev``, keeping host +
+        device plan memory O(ndev · chunk_shape).
+
+        Under ``emit="device"`` each dispatch is a **megastep**: the
+        producer coalesces up to K descriptor windows into one
+        fixed-shape ``(cap, words)`` batch
+        (:class:`repro.core.plan_stream.WindowBatcher`) and the device
+        scans them inside one compiled step
+        (:func:`_desc_megastep`), so Python dispatch cost — the
+        per-shard streams' Achilles' heel on fast devices with tiny
+        windows — is paid once per K windows.  K adapts live between 1
+        and ``max_windows_per_dispatch``: consumer stalls shrink it
+        (producer-bound: smaller batches keep the pipeline full),
+        producer backlog grows it (dispatch-bound: amortize more).
+        ``emit="host"`` keeps one window per dispatch as the oracle.
+
+        Partials merge on the host in int64 — integer sums, so any
+        landing order is bit-identical to the replicated and
+        single-device paths for every backend, orient and emit (the
+        relabeling is order-preserving and the pair partition is exact).
+
+        **Fault tolerance** rides on the same property: windows are
+        independent and the merge is order-invariant, so any window can
+        be re-dispatched (after a transient error or a corrupted
+        result) or re-routed to a surviving device (after its home
+        device is retired) without changing a single census bit.  Every
+        dispatch is retried up to ``max_retries`` with exponential
+        backoff; a device that exhausts the budget (or hits a
+        persistent injected fault) is retired and its shards' host
+        arrays are re-uploaded to a survivor, whose already-compiled
+        step drains the remaining queue; stalled producers are
+        restarted by the pipeline watchdog; and ``checkpoint=`` journals
+        every landed window so a killed run resumes to the exact same
+        census.  An optional :class:`repro.core.faults.FaultPlan`
+        injects deterministic failures at the producer / upload /
+        dispatch boundaries to exercise all of it.
+        """
         plan_seconds = 0.0
         if part is None:
             with span("census.plan", census=census) as plan:
@@ -1310,203 +1223,9 @@ class CensusEngine:
                                   max_items, self.ndev,
                                   mesh_shape=getattr(part, "mesh_shape",
                                                      None))
-        space = part.space
-        host = {"host_pair_seconds": plan_seconds,
-                "host_partition_seconds": partition.seconds,
-                "preprune_items": sum(sh.space.num_items_preprune
-                                      for sh in part.shards),
-                "search_iters": space.search_iters}
         upload = (4 * (1 + 3 * sched.desc_shape + sched.num_anchors)
                   if emit == "device"
                   else ITEM_BYTES * sched.chunk_shape)
-        if schedule == "async":
-            return self._run_partitioned_async(part, sched, progress,
-                                               emit, max_items, upload,
-                                               checkpoint=checkpoint,
-                                               census=census, host=host)
-        self.stats = st = EngineStats(
-            backend=self.backend, ndev=self.ndev, orient=space.orient,
-            streamed=max_items is not None, max_items=max_items,
-            chunks=sched.num_steps,
-            chunk_shape=sched.chunk_shape * self.ndev,
-            items=0,
-            peak_plan_bytes=ITEM_BYTES * sched.chunk_shape * self.ndev,
-            emit=emit,
-            desc_shape=sched.desc_shape if emit == "device" else 0,
-            plan_upload_bytes=upload, partitioned=True,
-            partition_shape=getattr(part, "mesh_shape", None),
-            shard_items=list(part.stats.shard_items),
-            graph_resident_bytes=part.stats.max_shard_bytes,
-            graph_replicated_bytes=part.stats.replicated_bytes,
-            schedule="lockstep", shard_steps=sched.shard_steps,
-            idle_steps=(sched.num_steps * self.ndev
-                        - sched.total_windows),
-            # real windows vs the empty padded lanes the barrier still
-            # ships: the physical upload is the sum of both totals
-            plan_upload_bytes_total=sched.total_windows * upload,
-            plan_pad_bytes_total=(sched.num_steps * self.ndev
-                                  - sched.total_windows) * upload,
-            dispatches_total=sched.num_steps,
-            windows_per_dispatch_mean=(
-                sched.total_windows / sched.num_steps
-                if sched.num_steps else 0.0),
-            # live lanes per step never exceed step 0's (shards only
-            # drain), so the max is the non-empty shard count
-            windows_per_dispatch_max=sum(
-                1 for t in sched.shard_steps if t > 0),
-            dispatch_batch_limit=1, **host)
-        with span("census.plan", st, "host_pair_seconds", census=census):
-            base_asym, base_mut = global_bases(space)
-        if sched.num_steps == 0:
-            return assemble_counts(space.n, base_asym, base_mut,
-                                   np.zeros(64, np.int64),
-                                   np.zeros(2, np.int64))
-
-        rep, dev_sh = self._shardings()
-        with span("census.partition", st, "host_partition_seconds",
-                  census=census):
-            arrs = stacked_device_arrays(part.shards)
-        with span("census.upload", census=census):
-            graph_dev = tuple(self._put(a, dev_sh) for a in arrs)
-            if emit == "device":
-                idx_dev = self._put(
-                    jnp.arange(sched.chunk_shape, dtype=jnp.int32), rep)
-        hist_acc = np.zeros(64, np.int64)
-        inter_acc = np.zeros(2, np.int64)
-        chunk_items: list[int] = []
-        pending = None
-        if emit == "device":
-            step = _part_desc_step
-            cache0 = _jit_cache_size(step)
-
-            def land(fut, k):
-                with span("chunk.land", st, "host_land_seconds",
-                          census=census, chunk=k):
-                    num = _land_desc_partials(fut, hist_acc, inter_acc,
-                                              chunk_items)
-                if progress is not None:
-                    progress(k, sched.num_steps, num)
-
-            for k in range(sched.num_steps):
-                with span("chunk.emit", st, "host_emit_seconds",
-                          census=census, chunk=k):
-                    words = sched.step_words(k)
-                with span("chunk.dispatch", census=census, chunk=k):
-                    fut = _launch(step, self.backend, *graph_dev,
-                                  self._put(words, dev_sh), idx_dev,
-                                  self.mesh, space.search_iters,
-                                  sched.desc_iters, self.backend,
-                                  space.orient, space.prune_self)
-                if pending is not None:
-                    land(pending, k - 1)
-                pending = fut
-            if pending is not None:
-                land(pending, sched.num_steps - 1)
-        else:
-            step = _part_chunk_step
-            cache0 = _jit_cache_size(step)
-
-            def land(fut, k):
-                with span("chunk.land", st, "host_land_seconds",
-                          census=census, chunk=k):
-                    np.add(hist_acc, np.asarray(fut[0], dtype=np.int64),
-                           out=hist_acc)
-                    np.add(inter_acc, np.asarray(fut[1], dtype=np.int64),
-                           out=inter_acc)
-
-            for k in range(sched.num_steps):
-                with span("chunk.emit", st, "host_emit_seconds",
-                          census=census, chunk=k):
-                    item_sp, item_pv, nums = sched.step_items(k)
-                chunk_items.append(int(sum(nums)))
-                if progress is not None:
-                    progress(k, sched.num_steps, chunk_items[-1])
-                with span("chunk.dispatch", census=census, chunk=k):
-                    fut = _launch(step, self.backend, *graph_dev,
-                                  self._put(item_sp, dev_sh),
-                                  self._put(item_pv, dev_sh),
-                                  self.mesh, space.search_iters,
-                                  self.backend)
-                if pending is not None:
-                    land(pending, k - 1)
-                pending = fut
-            if pending is not None:
-                land(pending, sched.num_steps - 1)
-
-        st.step_compiles = _jit_cache_size(step) - cache0
-        st.chunk_items = chunk_items
-        st.items = int(sum(chunk_items))
-        mono_wp = -(-st.items // self.ndev) * self.ndev
-        st.monolithic_plan_bytes = ITEM_BYTES * mono_wp
-        with span("census.assemble", census=census):
-            return assemble_counts(space.n, base_asym, base_mut,
-                                   hist_acc, inter_acc)
-
-    def _run_partitioned_async(self, part, sched: ShardSchedule,
-                               progress, emit: str,
-                               max_items: int | None,
-                               upload: int, *, census: int, host: dict,
-                               checkpoint: str | None = None
-                               ) -> np.ndarray:
-        """Async per-shard streams: every device drains its PRIVATE chunk
-        queue with no inter-shard barrier.
-
-        Instead of one collective dispatch per lock step (where the
-        longest shard's queue gates every device, and exhausted shards
-        burn whole steps on empty padded windows), each shard's real
-        windows are dispatched as independent single-device steps against
-        per-device-committed shard buffers — the
-        :class:`PartitionedEngineSession` dispatch discipline applied to
-        the full run.  A shard with 3 chunks is done after 3 dispatches
-        while a 12-chunk shard keeps going, so walltime tracks the MEAN
-        shard cost, not the max.
-
-        The host side is pipelined by a
-        :class:`repro.core.plan_stream.ShardStreamPipeline`: one
-        background producer per non-empty shard packs descriptor
-        windows / emits item batches ``pipeline_depth`` windows ahead
-        into its private queue, so window k+1's generation + upload
-        overlaps window k's compute (zero-window shards never get a
-        producer or a rotation slot); dispatches are async (futures)
-        with a bounded in-flight deque of ``2 * ndev``, keeping host +
-        device plan memory O(ndev · chunk_shape).  On accelerator
-        platforms the uploaded buffers are donated (:func:`_chunk_step`
-        / :func:`_desc_megastep`), so the double-buffered uploads reuse
-        HBM.
-
-        Under ``emit="device"`` each dispatch is a **megastep**: the
-        producer coalesces up to K descriptor windows into one
-        fixed-shape ``(cap, words)`` batch
-        (:class:`repro.core.plan_stream.WindowBatcher`) and the device
-        scans them inside one compiled step
-        (:func:`_desc_megastep`), so Python dispatch cost — the async
-        schedule's Achilles' heel on fast devices with tiny windows —
-        is paid once per K windows.  K adapts live between 1 and
-        ``max_windows_per_dispatch``: consumer stalls shrink it
-        (producer-bound: smaller batches keep the pipeline full),
-        producer backlog grows it (dispatch-bound: amortize more).
-        ``emit="host"`` keeps the PR 6 one-window-per-dispatch path as
-        the oracle.
-
-        Partials merge on the host in int64 — integer sums, so the
-        arbitrary landing order is bit-identical to the lock-step psum.
-
-        **Fault tolerance** rides on the same property: windows are
-        independent and the merge is order-invariant, so any window can
-        be re-dispatched (after a transient error or a corrupted
-        result) or re-routed to a surviving device (after its home
-        device is retired) without changing a single census bit.  Every
-        dispatch is retried up to ``max_retries`` with exponential
-        backoff; a device that exhausts the budget (or hits a
-        persistent injected fault) is retired and its shards' host
-        arrays are re-uploaded to a survivor, whose already-compiled
-        step drains the remaining queue; stalled producers are
-        restarted by the pipeline watchdog; and ``checkpoint=`` journals
-        every landed window so a killed run resumes to the exact same
-        census.  An optional :class:`repro.core.faults.FaultPlan`
-        injects deterministic failures at the producer / upload /
-        dispatch boundaries to exercise all of it.
-        """
         space = part.space
         ndev = self.ndev
         total_windows = sched.total_windows
@@ -1521,8 +1240,7 @@ class CensusEngine:
             backend=self.backend, ndev=ndev, orient=space.orient,
             streamed=max_items is not None, max_items=max_items,
             chunks=0, chunk_shape=sched.chunk_shape, items=0,
-            # the schedule-wide lane footprint (all devices), comparable
-            # with the lock-step record
+            # the schedule-wide lane footprint (all devices)
             peak_plan_bytes=ITEM_BYTES * sched.chunk_shape * ndev,
             emit=emit,
             desc_shape=sched.desc_shape if emit == "device" else 0,
@@ -1531,9 +1249,14 @@ class CensusEngine:
             shard_items=list(part.stats.shard_items),
             graph_resident_bytes=part.stats.max_shard_bytes,
             graph_replicated_bytes=part.stats.replicated_bytes,
-            schedule="async", shard_steps=[0] * ndev,
+            shard_steps=[0] * ndev,
             pipeline_depth=self.pipeline_depth,
-            dispatch_batch_limit=cap, **host)
+            dispatch_batch_limit=cap,
+            host_pair_seconds=plan_seconds,
+            host_partition_seconds=partition.seconds,
+            preprune_items=sum(sh.space.num_items_preprune
+                               for sh in part.shards),
+            search_iters=space.search_iters)
         with span("census.plan", st, "host_pair_seconds", census=census):
             base_asym, base_mut = global_bases(space)
         if total_windows == 0:
@@ -1572,7 +1295,7 @@ class CensusEngine:
         # gets a producer thread or a consumer rotation slot
         batcher = None
         if emit == "device":
-            step = _desc_megastep(self.mesh)
+            step = _desc_megastep
             batcher = WindowBatcher(
                 cap, 1 + 3 * sched.desc_shape + sched.num_anchors)
             # remaining window ids per shard in yield order — lets the
@@ -1596,7 +1319,7 @@ class CensusEngine:
                         yield words
                 return gen()
         else:
-            step = _chunk_step(self.mesh)
+            step = _chunk_step
             order = None
             live = [s for s in range(ndev) if sched.steps_for(s) > 0]
 
@@ -2035,7 +1758,7 @@ class EngineSession:
         #: upper bound keeps the jitted step valid for every graph revision
         self.search_iters = max(1, int(np.ceil(np.log2(max(g.n, 2)))))
         self._rep, self._item_sh = engine._shardings()
-        self._step = _chunk_step(engine.mesh)
+        self._step = _chunk_step
         self._cap_entries = 0
         self._cap_pairs = 0
         self._capacity_grew = False
@@ -2515,7 +2238,7 @@ class PartitionedEngineSession:
         self._devices = list(engine.mesh.devices.flat)
         #: pinned unrolled-search depth (see :class:`EngineSession`)
         self.search_iters = max(1, int(np.ceil(np.log2(max(g.n, 2)))))
-        self._step = _chunk_step(engine.mesh)
+        self._step = _chunk_step
         self._cap_n = self._cap_entries = self._cap_pairs = 0
         self._capacity_grew = False
         self.chunk_shape: int | None = None

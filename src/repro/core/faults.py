@@ -16,9 +16,9 @@ Fault sites
 ``upload``
     the ``device_put`` of a window's plan buffer onto its device.
 ``dispatch``
-    the compiled ``_desc_megastep`` / ``_part_desc_step`` /
-    ``_part_chunk_step`` call boundary (covers both the synchronous
-    trace/launch and the asynchronous materialization of the result).
+    the compiled ``_desc_megastep`` / ``_chunk_step`` call boundary
+    (covers both the synchronous trace/launch and the asynchronous
+    materialization of the result).
 
 Fault kinds
 -----------

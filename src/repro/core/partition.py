@@ -566,8 +566,8 @@ class GraphPartition2D:
     ``shards`` is the **flat** tile list — tile ``(s, j)`` (pair shard
     ``s``, vertex slice ``j``) sits at index ``s * V + j`` — so every
     consumer of the 1D partition's shard list (``ShardSchedule``,
-    ``stacked_device_arrays``, the async/lock-step/megastep dispatch
-    paths) runs unmodified over the 2D tile set; only ownership
+    ``stacked_device_arrays``, the per-shard megastep dispatch path)
+    runs unmodified over the 2D tile set; only ownership
     bookkeeping (one pair shard owns a pair, its V tiles split the
     pair's witness range) knows about the second axis.
     """

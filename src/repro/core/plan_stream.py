@@ -198,19 +198,10 @@ class ShardSchedule:
     any shard's window can have) — so one fixed-shape jitted step serves
     every shard's every window and compiles exactly once.
 
-    Two execution disciplines consume the same geometry:
-
-    * **Lock-step** (``schedule="lockstep"``): one collective dispatch
-      per step advances every device's queue together; ``num_steps`` is
-      the longest shard's step count and shorter shards pad with empty
-      windows (:meth:`step_words` / :meth:`step_items` stack all shards).
-      The bit-identity oracle.
-    * **Async** (``schedule="async"``, the default): each shard's private
-      queue is walked independently — :meth:`steps_for` real windows per
-      shard, no padding steps, no inter-shard barrier
-      (:meth:`shard_step_items` / :meth:`descriptors` serve one shard's
-      window at a time).  Walltime tracks the mean shard cost instead of
-      the max.
+    Each shard's private queue is walked independently — :meth:`steps_for`
+    real windows per shard, no padding steps, no inter-shard barrier
+    (:meth:`shard_step_items` / :meth:`descriptors` serve one shard's
+    window at a time).
     """
 
     def __init__(self, spaces, max_items: int | None, num_devices: int,
@@ -241,9 +232,6 @@ class ShardSchedule:
                 f"item indexing and would silently wrap the per-window "
                 f"int32 accumulator lanes; pass a smaller max_items "
                 f"budget (< 2**31 per device)")
-        self.num_steps = max(
-            (-(-s.num_items_preprune // self.chunk_shape)
-             for s in self.spaces), default=0)
         self.desc_shape = max(
             max_pairs_per_window(s.offsets, self.chunk_shape)
             for s in self.spaces) if self.spaces else 1
@@ -262,23 +250,18 @@ class ShardSchedule:
         return (s // self.mesh_shape[1], s % self.mesh_shape[1])
 
     def steps_for(self, s: int) -> int:
-        """Shard ``s``'s REAL step count: the windows that actually carry
-        pre-prune items (``num_steps`` minus this shard's lock-step
-        padding)."""
+        """Shard ``s``'s step count: the windows that carry its
+        pre-prune items."""
         return -(-self.spaces[s].num_items_preprune // self.chunk_shape)
 
     @property
     def shard_steps(self) -> list:
-        """Per-shard real step counts — the async schedule's work list
-        and the lock-step schedule's idle accounting
-        (``idle = num_steps * num_shards - sum(shard_steps)``)."""
+        """Per-shard step counts — the partitioned run's work list."""
         return [self.steps_for(s) for s in range(self.num_shards)]
 
     @property
     def total_windows(self) -> int:
-        """Total real windows across every shard — the async path's
-        dispatch count (lock-step dispatches
-        ``num_steps * num_shards`` window lanes instead)."""
+        """Total windows across every shard."""
         return sum(self.shard_steps)
 
     def _bounds(self, s: int, k: int) -> tuple[int, int]:
@@ -294,36 +277,17 @@ class ShardSchedule:
         return descriptor_window(self.spaces[s].offsets, lo, hi,
                                  self.desc_shape, self.num_anchors)
 
-    def step_words(self, k: int) -> np.ndarray:
-        """All shards' step-``k`` windows as one (num_shards, words) int32
-        buffer — the sharded per-step upload of the device-emission path."""
-        return np.stack([self.descriptors(s, k).device_words()
-                         for s in range(self.num_shards)])
-
     def shard_step_items(self, s: int, k: int
                          ) -> tuple[np.ndarray, np.ndarray, int]:
         """Shard ``s``'s step-``k`` packed item window
-        ((chunk_shape,) sp/pv words + valid item count) — the per-shard
-        unit the async path dispatches one at a time."""
+        ((chunk_shape,) sp/pv words + valid item count) — the unit the
+        host-emission path dispatches one at a time."""
         lo, hi = self._bounds(s, k)
         item_pair, item_slot, item_side = emit_items(self.spaces[s],
                                                      lo, hi)
         sp, pv = pad_and_pack(item_pair, item_slot, item_side,
                               self.chunk_shape)
         return sp, pv, int(item_pair.shape[0])
-
-    def step_items(self, k: int
-                   ) -> tuple[np.ndarray, np.ndarray, list[int]]:
-        """All shards' step-``k`` packed item windows, stacked
-        (num_shards, chunk_shape), plus per-shard valid item counts — the
-        host-emission twin of :meth:`step_words`."""
-        sps, pvs, nums = [], [], []
-        for s in range(self.num_shards):
-            sp, pv, num = self.shard_step_items(s, k)
-            nums.append(num)
-            sps.append(sp)
-            pvs.append(pv)
-        return np.stack(sps), np.stack(pvs), nums
 
 
 #: end-of-stream sentinel of :class:`ShardStreamPipeline` producers

@@ -32,7 +32,7 @@ changed (:mod:`repro.core.incremental`).  This is **bit-identical** to a
 from-scratch census of window k+1 on every backend and orient mode — the
 incremental path changes the work done, never the counts — and processes
 O(affected) work items instead of the window's full O(W)
-(`tests/test_temporal.py`, `benchmarks/check.sh --temporal-smoke`).
+(`tests/test_temporal.py`).
 
 With ``partition=True`` (and a mesh) the session shards each window's
 graph itself: every device holds only its pair shard's local subgraph,
@@ -109,9 +109,6 @@ class TriadMonitor:
         per-window :class:`~repro.core.engine.EngineStats` carry the
         shard balance/residency report.  Requires ``mesh``; censuses are
         bit-identical either way.
-    schedule : partitioned full-run execution discipline (``"async"``
-        per-shard streams by default, ``"lockstep"`` the collective
-        oracle); forwarded to the engine, bit-identical either way.
     pipeline_depth : per-shard produced-window queue depth of the async
         host pipeline (default 2 — double-buffering); forwarded to the
         engine and surfaced in each window's
@@ -151,7 +148,6 @@ class TriadMonitor:
                  max_items: int | None = None,
                  emit: str | None = None,
                  partition: bool = False,
-                 schedule: str = "async",
                  pipeline_depth: int = PIPELINE_DEPTH,
                  max_windows_per_dispatch: int =
                  MAX_WINDOWS_PER_DISPATCH,
@@ -189,7 +185,7 @@ class TriadMonitor:
         self.index = bool(index)
         self.engine = CensusEngine(
             mesh=mesh, backend=backend, partition=partition,
-            schedule=schedule, pipeline_depth=pipeline_depth,
+            pipeline_depth=pipeline_depth,
             max_windows_per_dispatch=max_windows_per_dispatch,
             faults=faults, max_retries=max_retries,
             retry_backoff=retry_backoff,

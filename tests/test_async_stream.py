@@ -2,20 +2,19 @@
 
 The tentpole contract:
 
-* **Bit-identity** — ``schedule="async"`` (independent per-device
-  dispatches, host int64 merge) equals ``schedule="lockstep"`` (the
-  collective psum oracle) equals the reference census, across
-  1/2/4/8-device meshes × both orients × both emit modes, on balanced,
-  skewed and empty-shard partitions.  Integer sums make the merge order
-  unobservable.
-* **No cross-shard synchronization** — the async path never enters the
-  collective lock-step primitives (``_part_desc_step`` /
-  ``_part_chunk_step``); each window is a single-device dispatch.
+* **Bit-identity** — the partitioned run (independent per-device
+  dispatches, host int64 merge) equals the Batagelj–Mrvar reference and
+  the single-device census, across 1/2/4/8-device meshes × both orients
+  × both emit modes, on balanced, skewed and empty-shard partitions.
+  Integer sums make the merge order unobservable.
+* **No cross-shard synchronization** — every step a partitioned run
+  launches takes arrays committed to one device; no input spans the
+  mesh, so no collective can run.
 * **Skew** — a shard with 4× everyone else's chunk queue finishes late
   WITHOUT holding the other shards' queues: total dispatches equal the
   sum of real windows, not ``ndev × max``.
-* **Stats** — per-shard step counts, stall/idle counters, pipeline depth
-  and per-shard upload attribution are exact under both schedules.
+* **Stats** — per-shard step counts, stall counters, pipeline depth and
+  per-shard upload attribution are exact.
 """
 
 import numpy as np
@@ -127,7 +126,6 @@ class TestPerShardSchedule:
         sched = ShardSchedule([sh.space for sh in part.shards], 200, 4)
         steps = sched.shard_steps
         assert steps == [sched.steps_for(s) for s in range(4)]
-        assert sched.num_steps == max(steps)
         assert sched.total_windows == sum(steps)
         # the skew helper really skews the queue lengths
         assert steps[0] >= 3 * max(steps[1:])
@@ -152,33 +150,34 @@ class TestAsyncBitIdentity:
     @pytest.mark.parametrize("num_devices", [1, 2, 4, 8])
     @pytest.mark.parametrize("orient", ["none", "degree"])
     @pytest.mark.parametrize("emit", ["device", "host"])
-    def test_async_equals_lockstep_and_reference(self, num_devices,
-                                                 orient, emit):
+    def test_partitioned_equals_single_device_and_reference(
+            self, num_devices, orient, emit):
         g = pl_graph(n=70, seed=5)
         want = census_batagelj_mrvar(g)
-        got = {}
-        for sched in ("async", "lockstep"):
-            engine = CensusEngine(mesh=default_mesh(num_devices),
-                                  backend="jnp", partition=True,
-                                  emit=emit, schedule=sched)
-            got[sched] = engine.run(g, max_items=120, orient=orient)
-        np.testing.assert_array_equal(got["async"], want)
-        np.testing.assert_array_equal(got["async"], got["lockstep"])
+        engine = CensusEngine(mesh=default_mesh(num_devices),
+                              backend="jnp", partition=True, emit=emit)
+        got = engine.run(g, max_items=120, orient=orient)
+        np.testing.assert_array_equal(got, want)
+        single = CensusEngine(backend="jnp", emit=emit)
+        np.testing.assert_array_equal(
+            single.run(g, max_items=120, orient=orient), got)
 
     @pytest.mark.parametrize("emit", ["device", "host"])
     def test_skewed_partition_bit_identical(self, emit):
         g = pl_graph(n=90, seed=11)
         want = census_batagelj_mrvar(g)
         part = skewed_partition(g, 4)
-        for sched in ("async", "lockstep"):
-            engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                                  partition=True, emit=emit,
-                                  schedule=sched)
-            got = engine.run(g, max_items=200, part=part)
-            np.testing.assert_array_equal(got, want)
-        # async dispatched only the real windows: Σ steps, not ndev×max
-        st = engine.stats          # lockstep (last): padded idle steps
-        assert st.idle_steps > 0
+        engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
+                              partition=True, emit=emit)
+        got = engine.run(g, max_items=200, part=part)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(
+            CensusEngine(backend="jnp", emit=emit).run(g, max_items=200),
+            got)
+        # only the real windows ran: Σ steps, not ndev × the longest
+        st = engine.stats
+        assert st.chunks == sum(st.shard_steps) \
+            < 4 * max(st.shard_steps)
 
     def test_empty_shards_both_schedules(self):
         g = pl_graph(n=50, seed=13)
@@ -186,19 +185,20 @@ class TestAsyncBitIdentity:
         space = pair_space(g)
         owner = np.zeros(space.num_pairs, np.int64)   # all pairs → 0
         part = partition_graph(num_shards=4, space=space, owner=owner)
-        for sched in ("async", "lockstep"):
-            engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                                  partition=True, schedule=sched)
-            got = engine.run(g, max_items=150, part=part)
-            np.testing.assert_array_equal(got, want)
-            assert engine.stats.shard_steps[1:] == [0, 0, 0]
+        engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
+                              partition=True)
+        got = engine.run(g, max_items=150, part=part)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(
+            CensusEngine(backend="jnp").run(g, max_items=150), got)
+        assert engine.stats.shard_steps[1:] == [0, 0, 0]
 
     @pytest.mark.parametrize("backend", ["pallas", "pallas-fused"])
     def test_async_backends(self, backend):
         g = pl_graph(n=40, deg=4, seed=8)
         want = census_batagelj_mrvar(g)
         engine = CensusEngine(mesh=default_mesh(4), backend=backend,
-                              partition=True, schedule="async")
+                              partition=True)
         np.testing.assert_array_equal(engine.run(g), want)
         np.testing.assert_array_equal(engine.run(g, max_items=80), want)
 
@@ -206,16 +206,8 @@ class TestAsyncBitIdentity:
         """max_items=None still works: one window per shard."""
         g = pl_graph(n=60, seed=19)
         got = triad_census_graph(g, mesh=default_mesh(4),
-                                 partition=True, schedule="async")
+                                 partition=True)
         np.testing.assert_array_equal(got, census_batagelj_mrvar(g))
-
-    def test_schedule_validation(self):
-        with pytest.raises(ValueError, match="schedule"):
-            CensusEngine(mesh=default_mesh(2), partition=True,
-                         schedule="bogus")
-        engine = CensusEngine(mesh=default_mesh(2), partition=True)
-        with pytest.raises(ValueError, match="schedule"):
-            engine.run(pl_graph(n=20), schedule="bogus")
 
     def test_prebuilt_part_validation(self):
         g = pl_graph(n=30, seed=1)
@@ -231,105 +223,80 @@ class TestAsyncBitIdentity:
 # ------------------------------------------------------ no-sync proof
 
 
+def single_device_launches(monkeypatch):
+    """Spy on ``_launch``: record, for every step a run launches, the
+    device sets of its array arguments."""
+    import jax
+
+    import repro.core.engine as engine_mod
+    seen = []
+    real = engine_mod._launch
+
+    def spy(step, backend, *args):
+        seen.append([a.devices() for a in args
+                     if isinstance(a, jax.Array)])
+        return real(step, backend, *args)
+
+    monkeypatch.setattr(engine_mod, "_launch", spy)
+    return seen
+
+
 class TestNoCrossShardSync:
     @pytest.mark.parametrize("emit", ["device", "host"])
     def test_async_never_enters_collective_step(self, emit, monkeypatch):
-        """The lock-step path's collective primitives are the ONLY
-        cross-shard synchronization points; poisoning them proves the
-        async schedule never synchronizes shards between chunk steps."""
-        import repro.core.engine as engine_mod
-
-        def poison(*a, **k):
-            raise AssertionError("async schedule entered the "
-                                 "collective lock-step primitive")
-
-        monkeypatch.setattr(engine_mod, "_part_desc_step", poison)
-        monkeypatch.setattr(engine_mod, "_part_chunk_step", poison)
+        """Every launched step takes arrays committed to one device, the
+        same one for all of a launch's arguments: no input spans the
+        mesh, so no step can synchronize shards."""
+        seen = single_device_launches(monkeypatch)
         g = pl_graph(n=70, seed=23)
         engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                              partition=True, emit=emit,
-                              schedule="async")
+                              partition=True, emit=emit)
         got = engine.run(g, max_items=150)
         np.testing.assert_array_equal(got, census_batagelj_mrvar(g))
-
-    def test_lockstep_does_use_collective_step(self, monkeypatch):
-        """Control for the poison test: the oracle path DOES go through
-        the collective primitive."""
-        import repro.core.engine as engine_mod
-        calls = []
-        real = engine_mod._part_desc_step
-
-        def spy(*a, **k):
-            calls.append(1)
-            return real(*a, **k)
-
-        spy._cache_size = real._cache_size
-        monkeypatch.setattr(engine_mod, "_part_desc_step", spy)
-        g = pl_graph(n=40, seed=23)
-        engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                              partition=True, emit="device",
-                              schedule="lockstep")
-        engine.run(g, max_items=150)
-        assert calls
+        assert len(seen) == engine.stats.dispatches_total > 4
+        for devs in seen:
+            assert devs and all(len(d) == 1 for d in devs)
+            assert len(set().union(*devs)) == 1
+        # the launches cover more than one device
+        assert len(set().union(*(d for devs in seen for d in devs))) > 1
 
 
 # -------------------------------------------------------------- stats
 
 
 class TestAsyncStats:
-    def test_lockstep_vs_async_stats_regression(self):
-        """Satellite: upload/step attribution under async.  Same census,
-        same items, same per-shard step counts; both schedules attribute
-        upload to REAL windows, with padding split into a separate
-        counter (lock-step burns whole idle collective steps; async pads
-        only ragged megabatch tails)."""
+    def test_upload_and_step_stats_regression(self):
+        """Upload/step attribution of a partitioned run: per-shard step
+        counts match the shard schedule, upload is charged to REAL
+        windows only, and the padding of ragged megabatch tails goes to
+        a separate counter."""
         g = pl_graph(n=90, seed=11)
         part = skewed_partition(g, 4)
-        st = {}
-        census = {}
-        for sched in ("async", "lockstep"):
-            engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                                  partition=True, emit="device",
-                                  schedule=sched)
-            census[sched] = engine.run(g, max_items=200, part=part)
-            st[sched] = engine.stats
-        a, l = st["async"], st["lockstep"]
-        np.testing.assert_array_equal(census["async"],
-                                      census["lockstep"])
-        assert a.items == l.items > 0
-        assert a.schedule == "async" and l.schedule == "lockstep"
-        # identical queues, so identical per-shard step counts
-        assert a.shard_steps == l.shard_steps
+        engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
+                              partition=True, emit="device")
+        census = engine.run(g, max_items=200, part=part)
+        a = engine.stats
+        np.testing.assert_array_equal(census, census_batagelj_mrvar(g))
+        assert a.items > 0
         sched_obj = ShardSchedule(
             [sh.space for sh in part.shards], 200, 4)
         assert a.shard_steps == sched_obj.shard_steps
-        # async dispatches exactly the real windows; lock-step burns
-        # whole collective steps on exhausted shards
+        # exactly the real windows are dispatched
         assert a.chunks == sum(a.shard_steps)
-        assert a.idle_steps == 0
-        assert l.idle_steps == 4 * max(l.shard_steps) \
-            - sum(l.shard_steps) > 0
-        # upload attribution: both schedules charge upload for REAL
-        # windows only; lock-step's padded idle steps land in the pad
-        # counter instead of inflating the upload total
+        # upload attribution: charged for REAL windows only
         assert a.plan_upload_bytes_total == \
             a.plan_upload_bytes * sum(a.shard_steps)
-        assert l.plan_upload_bytes_total == \
-            l.plan_upload_bytes * sum(l.shard_steps)
-        assert a.plan_upload_bytes_total == l.plan_upload_bytes_total
-        assert l.plan_pad_bytes_total == \
-            l.plan_upload_bytes * l.idle_steps > 0
-        # async pad obeys the megabatch identity: cap × dispatches
-        # minus real windows, all ragged-tail slots
+        # pad obeys the megabatch identity: cap × dispatches minus real
+        # windows, all ragged-tail slots
         assert a.plan_pad_bytes_total == a.plan_upload_bytes * \
             (a.dispatch_batch_limit * a.dispatches_total
              - sum(a.shard_steps))
         # pipeline surface
         assert a.pipeline_depth == 2
         assert a.stall_steps >= 0
-        assert "async" in a.summary() and "lockstep" in l.summary()
-        # comparable lane footprint records
-        assert a.peak_plan_bytes == l.peak_plan_bytes
+        assert "partitioned" in a.summary()
+        # the schedule-wide lane footprint covers every device
+        assert a.peak_plan_bytes == 8 * sched_obj.chunk_shape * 4
 
     def test_async_compiles_once_per_device_not_per_step(self):
         """The stacked common-shape shard buffers mean one compiled step
@@ -337,7 +304,7 @@ class TestAsyncStats:
         placement, so the floor is ndev, never O(steps))."""
         g = pl_graph(n=90, seed=21)
         engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                              partition=True, schedule="async")
+                              partition=True)
         engine.run(g, max_items=64)
         assert engine.stats.chunks >= 8
         assert engine.stats.step_compiles <= 4
@@ -347,8 +314,7 @@ class TestAsyncStats:
         counts only real dispatches."""
         g = pl_graph(n=60, seed=29)
         engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                              partition=True, emit="host",
-                              schedule="async")
+                              partition=True, emit="host")
         engine.run(g, max_items=100)
         st = engine.stats
         assert st.chunks == len(st.chunk_items)

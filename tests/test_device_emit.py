@@ -17,7 +17,8 @@ from repro.core import census
 from repro.core import (
     CensusEngine, PlanChunker, apply_delta, census_batagelj_mrvar,
     default_mesh, descriptor_window, from_edges, iter_descriptor_windows,
-    pair_space, scale_free_digraph, triad_census_graph)
+    monitor_stream, pair_space, paper_workload, scale_free_digraph,
+    triad_census_graph)
 from repro.core.planner import (
     DESC_ANCHOR_STRIDE, DESC_CUM_PAD, emit_items, num_desc_anchors,
     prune_items)
@@ -261,6 +262,36 @@ class TestDeviceEmitParity:
         assert dev.stats.items == host.stats.items
         assert dev.stats.plan_upload_bytes < host.stats.plan_upload_bytes
 
+    @pytest.mark.parametrize("backend,orient", [
+        ("jnp", "none"), ("jnp", "degree"), ("pallas-fused", "none")])
+    def test_upload_cut_fourfold(self, backend, orient):
+        """Streamed runs and incremental session updates: device
+        emission equals host emission, chunk for chunk, while shipping
+        at least 4x fewer plan bytes per dispatch."""
+        g = paper_workload("orkut", n=400, avg_degree=12.0, seed=0)
+        max_items = max(pair_space(g).num_items_preprune // 6, 1)
+        eng = {e: CensusEngine(backend=backend, emit=e)
+               for e in ("host", "device")}
+        got = {e: eng[e].run(g, max_items=max_items, orient=orient)
+               for e in eng}
+        np.testing.assert_array_equal(got["device"], got["host"])
+        st_h, st_d = eng["host"].stats, eng["device"].stats
+        assert st_h.chunks >= 4
+        assert st_d.chunk_items == st_h.chunk_items
+        assert st_h.plan_upload_bytes >= 4 * st_d.plan_upload_bytes
+        rng = np.random.default_rng(1)
+        add = (rng.integers(0, 400, 60), rng.integers(0, 400, 60))
+        rem = (rng.integers(0, 400, 60), rng.integers(0, 400, 60))
+        ses = {e: CensusEngine(backend=backend, emit=e).session(
+            g, orient=orient, max_items=max_items)
+            for e in ("host", "device")}
+        np.testing.assert_array_equal(ses["device"].census(),
+                                      ses["host"].census())
+        np.testing.assert_array_equal(ses["device"].update(*add, *rem),
+                                      ses["host"].update(*add, *rem))
+        assert ses["host"].stats.plan_upload_bytes >= \
+            4 * ses["device"].stats.plan_upload_bytes
+
     def test_mesh_device_emit(self):
         g = scale_free_digraph(n=50, avg_degree=5, exponent=2.2,
                                mutual_p=0.3, seed=8)
@@ -333,6 +364,30 @@ class TestDeviceEmitSession:
             got = session.update(*add, *rem)
             g, _ = apply_delta(g, *add, *rem)
             np.testing.assert_array_equal(got, census_batagelj_mrvar(g))
+
+    @pytest.mark.parametrize("emit", ["host", "device"])
+    def test_reciprocal_updates_restore_census(self, emit):
+        """Adding arcs and then deleting the same arcs returns the
+        session to its exact starting census."""
+        rng = np.random.default_rng(0)
+        window = 4000
+        src, dst, n = monitor_stream(rng, 80, 3000, 800, 2 * window)
+        g = from_edges(src[:window], dst[:window], n=n)
+        # arcs of the next window absent from g, so add then delete
+        # restores g exactly (set semantics)
+        base = src[:window] * n + dst[:window]
+        cand_s, cand_d = src[window:], dst[window:]
+        fresh = ~np.isin(cand_s * n + cand_d, base) & (cand_s != cand_d)
+        d_src, d_dst = cand_s[fresh][:400], cand_d[fresh][:400]
+        session = CensusEngine(backend="jnp", emit=emit).session(
+            g, max_items=4096)
+        want = session.census()
+        np.testing.assert_array_equal(want, census_batagelj_mrvar(g))
+        mid = session.update(d_src, d_dst)
+        assert (mid != want).any()
+        back = session.update(del_src=d_src, del_dst=d_dst)
+        np.testing.assert_array_equal(back, want)
+        assert session.stats.affected_pairs > 0
 
     def test_device_session_matches_host_session_stats(self):
         rng = np.random.default_rng(17)

@@ -238,7 +238,7 @@ class TestFaultedRunsBitIdentical:
             31 + ndev, ndev, producer_errors=1, dispatch_errors=1,
             retire_devices=1 if ndev > 1 else 0)
         eng = CensusEngine(mesh=default_mesh(ndev), partition=True,
-                           schedule="async", faults=plan,
+                           faults=plan,
                            retry_backoff=0.0)
         got = eng.run(g, max_items=900, emit=emit)
         assert (got == reference["none"]).all()
@@ -252,7 +252,7 @@ class TestFaultedRunsBitIdentical:
         plan = FaultPlan.seeded(5, 8, producer_errors=1,
                                 dispatch_errors=2, retire_devices=1)
         eng = CensusEngine(mesh=default_mesh(8), partition=True,
-                           schedule="async", faults=plan,
+                           faults=plan,
                            retry_backoff=0.0)
         got = eng.run(g, max_items=900, orient=orient)
         assert (got == reference[orient]).all()
@@ -263,7 +263,7 @@ class TestFaultedRunsBitIdentical:
                                 dispatch_errors=0, delays=2, poisons=2,
                                 delay_seconds=0.05)
         eng = CensusEngine(mesh=default_mesh(4), partition=True,
-                          schedule="async", faults=plan,
+                          faults=plan,
                           retry_backoff=0.0)
         got = eng.run(g, max_items=900)
         assert (got == reference["none"]).all()
@@ -274,7 +274,7 @@ class TestFaultedRunsBitIdentical:
             Fault("dispatch", "error", device=d, occurrence=0,
                   persistent=True) for d in range(2)])
         eng = CensusEngine(mesh=default_mesh(2), partition=True,
-                          schedule="async", faults=plan,
+                          faults=plan,
                           retry_backoff=0.0)
         with pytest.raises(FaultError, match="every device"):
             eng.run(g, max_items=900)
@@ -283,7 +283,7 @@ class TestFaultedRunsBitIdentical:
         plan = FaultPlan.seeded(5, 8, producer_errors=1,
                                 dispatch_errors=2, retire_devices=1)
         eng = CensusEngine(mesh=default_mesh(8), partition=True,
-                          schedule="async", faults=plan,
+                          faults=plan,
                           retry_backoff=0.0)
         eng.run(g, max_items=900)
         part = partition_graph(g, num_shards=8)
@@ -314,8 +314,7 @@ class TestCheckpointResume:
     def test_resume_equals_uninterrupted(self, tmp_path, g, reference,
                                          emit):
         ck = str(tmp_path / "run.ckpt")
-        eng = CensusEngine(mesh=default_mesh(4), partition=True,
-                          schedule="async")
+        eng = CensusEngine(mesh=default_mesh(4), partition=True)
         with pytest.raises(KeyboardInterrupt):
             eng.run(g, max_items=900, emit=emit, checkpoint=ck,
                     progress=_Killer(4))
@@ -329,14 +328,13 @@ class TestCheckpointResume:
         device — the journal windows stay skipped, the remainder fails
         over, and the census is still exact."""
         ck = str(tmp_path / "run.ckpt")
-        eng = CensusEngine(mesh=default_mesh(4), partition=True,
-                          schedule="async")
+        eng = CensusEngine(mesh=default_mesh(4), partition=True)
         with pytest.raises(KeyboardInterrupt):
             eng.run(g, max_items=900, checkpoint=ck, progress=_Killer(3))
         plan = FaultPlan.seeded(2, 4, producer_errors=0,
                                 dispatch_errors=1, retire_devices=1)
         eng2 = CensusEngine(mesh=default_mesh(4), partition=True,
-                           schedule="async", faults=plan,
+                           faults=plan,
                            retry_backoff=0.0)
         got = eng2.resume(g, ck, max_items=900)
         assert (got == reference["none"]).all()
@@ -346,8 +344,7 @@ class TestCheckpointResume:
     def test_completed_checkpoint_dispatches_nothing(self, tmp_path, g,
                                                      reference):
         ck = str(tmp_path / "run.ckpt")
-        eng = CensusEngine(mesh=default_mesh(4), partition=True,
-                          schedule="async")
+        eng = CensusEngine(mesh=default_mesh(4), partition=True)
         want = eng.run(g, max_items=900, checkpoint=ck)
         assert (want == reference["none"]).all()
         windows = eng.stats.resumed_windows + sum(eng.stats.shard_steps)
@@ -358,8 +355,7 @@ class TestCheckpointResume:
 
     def test_fingerprint_mismatch_rejected(self, tmp_path, g):
         ck = str(tmp_path / "run.ckpt")
-        eng = CensusEngine(mesh=default_mesh(4), partition=True,
-                          schedule="async")
+        eng = CensusEngine(mesh=default_mesh(4), partition=True)
         eng.run(g, max_items=900, checkpoint=ck)
         other = pl_graph(seed=99)
         with pytest.raises(FaultError, match="different run"):
@@ -372,8 +368,7 @@ class TestCheckpointResume:
                     checkpoint=str(tmp_path / "x.ckpt"))
 
     def test_resume_missing_file_raises(self, g):
-        eng = CensusEngine(mesh=default_mesh(4), partition=True,
-                          schedule="async")
+        eng = CensusEngine(mesh=default_mesh(4), partition=True)
         with pytest.raises(FileNotFoundError):
             eng.resume(g, "/nonexistent/run.ckpt", max_items=900)
 
@@ -383,16 +378,14 @@ class TestCheckpointResume:
         compacted form — same census, smaller file, and a second
         compaction after completion leaves one record per shard."""
         ck = str(tmp_path / "run.ckpt")
-        eng = CensusEngine(mesh=default_mesh(4), partition=True,
-                          schedule="async")
+        eng = CensusEngine(mesh=default_mesh(4), partition=True)
         with pytest.raises(KeyboardInterrupt):
             eng.run(g, max_items=900, checkpoint=ck, progress=_Killer(4))
         info = CensusEngine.compact_checkpoint(ck)
         assert info["records"] >= info["compacted"] >= 1
         assert info["compacted_bytes"] == os.path.getsize(ck)
         assert info["compacted_bytes"] <= info["bytes"]
-        eng2 = CensusEngine(mesh=default_mesh(4), partition=True,
-                           schedule="async")
+        eng2 = CensusEngine(mesh=default_mesh(4), partition=True)
         got = eng2.resume(g, ck, max_items=900)
         assert (got == reference["none"]).all()
         assert eng2.stats.resumed_windows >= 1

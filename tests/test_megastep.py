@@ -2,9 +2,9 @@
 
 The tentpole contract:
 
-* **Bit-identity** — ``schedule="async"`` with any
-  ``max_windows_per_dispatch`` K equals the lock-step collective oracle
-  equals the reference census, across 1/2/4/8-device meshes × both
+* **Bit-identity** — a partitioned run with any
+  ``max_windows_per_dispatch`` K equals the single-device census and
+  the reference census, across 1/2/4/8-device meshes × both
   orients × both emit modes × K∈{1,2,8}.  The megastep returns per-
   window STACKED int32 partials and the host sums them in int64, so a
   K-window scan is bit-identical to K single-window dispatches.
@@ -17,8 +17,8 @@ The tentpole contract:
 * **Adaptive K** — consumer stalls shrink K (producer-bound), producer
   backlog grows K (dispatch-bound), monotonically within [1, cap].
 * **Short-circuit** — zero-window shards never get a producer thread
-  or a rotation slot, and the megastep path never enters the
-  cross-shard collective primitives.
+  or a rotation slot, and every megastep takes arrays committed to one
+  device: no cross-shard collective can run.
 """
 
 import time
@@ -30,6 +30,7 @@ from repro.core import (
     CensusEngine, ShardStreamPipeline, TriadMonitor, WindowBatcher,
     census_batagelj_mrvar, default_mesh, lpt_assign_heap, pair_space,
     partition_graph, scale_free_digraph)
+from repro.core.plan_stream import ShardSchedule
 
 
 def pl_graph(n=100, deg=5, seed=7):
@@ -189,33 +190,33 @@ class TestAdaptiveK:
 class TestMegastepBitIdentity:
     @pytest.mark.parametrize("orient", ["none", "degree"])
     @pytest.mark.parametrize("cap", [1, 2, 8])
-    def test_k_matrix_vs_lockstep_and_reference(self, cap, orient):
+    def test_k_matrix_vs_single_device_and_reference(self, cap, orient):
         g = pl_graph(n=70, seed=13)
         want = census_batagelj_mrvar(g)
         part = skewed_partition(g, 4, orient=orient)
-        lock = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                            partition=True, emit="device",
-                            schedule="lockstep")
-        ref = lock.run(g, max_items=120, part=part)
-        np.testing.assert_array_equal(ref, want)
+        single = CensusEngine(backend="jnp", emit="device")
+        np.testing.assert_array_equal(
+            single.run(g, max_items=120, orient=orient), want)
         eng = CensusEngine(mesh=default_mesh(4), backend="jnp",
                            partition=True, emit="device",
-                           schedule="async",
                            max_windows_per_dispatch=cap)
         got = eng.run(g, max_items=120, part=part)
         np.testing.assert_array_equal(got, want)
         st = eng.stats
         assert st.dispatch_batch_limit == cap
         assert 1 <= st.windows_per_dispatch_max <= cap
-        # same windows as the lock-step oracle, fewer dispatches
-        assert st.shard_steps == lock.stats.shard_steps
+        # every window of the shard schedule, in at most as many
+        # dispatches
+        sched = ShardSchedule([sh.space for sh in part.shards], 120, 4)
+        assert st.shard_steps == sched.shard_steps
+        assert st.dispatches_total <= sum(st.shard_steps)
 
     @pytest.mark.parametrize("ndev", [1, 2, 8])
     def test_device_count_sweep(self, ndev):
         g = pl_graph(n=60, seed=5)
         want = census_batagelj_mrvar(g)
         eng = CensusEngine(mesh=default_mesh(ndev), backend="jnp",
-                           partition=True, schedule="async",
+                           partition=True,
                            max_windows_per_dispatch=8)
         np.testing.assert_array_equal(eng.run(g, max_items=100), want)
 
@@ -224,7 +225,7 @@ class TestMegastepBitIdentity:
         g = pl_graph(n=40, deg=4, seed=8)
         want = census_batagelj_mrvar(g)
         eng = CensusEngine(mesh=default_mesh(4), backend=backend,
-                           partition=True, schedule="async",
+                           partition=True,
                            max_windows_per_dispatch=4)
         np.testing.assert_array_equal(eng.run(g, max_items=80), want)
 
@@ -234,7 +235,6 @@ class TestMegastepBitIdentity:
         g = pl_graph(n=60, seed=29)
         eng = CensusEngine(mesh=default_mesh(4), backend="jnp",
                            partition=True, emit="host",
-                           schedule="async",
                            max_windows_per_dispatch=8)
         np.testing.assert_array_equal(eng.run(g, max_items=100),
                                       census_batagelj_mrvar(g))
@@ -254,7 +254,7 @@ class TestMegastepStats:
         g = pl_graph(n=70, seed=13)
         part = skewed_partition(g, 4)
         eng = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                           partition=True, schedule="async",
+                           partition=True,
                            max_windows_per_dispatch=8)
         eng.run(g, max_items=120, part=part)
         st = eng.stats
@@ -278,7 +278,7 @@ class TestMegastepStats:
         shard, zero pad bytes."""
         g = pl_graph(n=40, deg=3, seed=2)
         eng = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                           partition=True, schedule="async",
+                           partition=True,
                            max_windows_per_dispatch=64)
         got = eng.run(g)                  # unstreamed: 1 window/shard
         np.testing.assert_array_equal(got, census_batagelj_mrvar(g))
@@ -295,7 +295,7 @@ class TestMegastepStats:
         disp = {}
         for cap in (1, 8):
             eng = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                               partition=True, schedule="async",
+                               partition=True,
                                max_windows_per_dispatch=cap)
             eng.run(g, max_items=100, part=part)
             disp[cap] = eng.stats.dispatches_total
@@ -311,28 +311,13 @@ class TestMegastepStats:
         and a second run recompiles nothing."""
         g = pl_graph(n=90, seed=21)
         eng = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                           partition=True, schedule="async",
+                           partition=True,
                            max_windows_per_dispatch=8)
         eng.run(g, max_items=64)
         assert eng.stats.dispatches_total >= 4
         assert eng.stats.step_compiles <= 4
         eng.run(g, max_items=64)          # warm cache
         assert eng.stats.step_compiles == 0
-
-    def test_lockstep_stats_surface(self):
-        g = pl_graph(n=70, seed=13)
-        part = skewed_partition(g, 4)
-        eng = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                           partition=True, emit="device",
-                           schedule="lockstep")
-        eng.run(g, max_items=120, part=part)
-        st = eng.stats
-        assert st.dispatch_batch_limit == 1
-        assert st.dispatches_total == st.chunks
-        assert st.windows_per_dispatch_mean == \
-            pytest.approx(sum(st.shard_steps) / st.dispatches_total)
-        assert st.windows_per_dispatch_max == \
-            sum(1 for t in st.shard_steps if t > 0)
 
     def test_ctor_validation(self):
         with pytest.raises(ValueError):
@@ -345,7 +330,7 @@ class TestMegastepStats:
     def test_pipeline_depth_configurable_and_surfaced(self):
         g = pl_graph(n=50, seed=4)
         eng = CensusEngine(mesh=default_mesh(2), backend="jnp",
-                           partition=True, schedule="async",
+                           partition=True,
                            pipeline_depth=3)
         np.testing.assert_array_equal(eng.run(g, max_items=80),
                                       census_batagelj_mrvar(g))
@@ -385,7 +370,7 @@ class TestShortCircuitAndIsolation:
             num_shards=4, space=space,
             owner=np.zeros(space.num_pairs, np.int64))
         eng = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                           partition=True, schedule="async",
+                           partition=True,
                            max_windows_per_dispatch=8)
         got = eng.run(g, max_items=100, part=part)
         np.testing.assert_array_equal(got, census_batagelj_mrvar(g))
@@ -396,20 +381,28 @@ class TestShortCircuitAndIsolation:
 
     @pytest.mark.parametrize("cap", [2, 8])
     def test_megastep_never_enters_collectives(self, cap, monkeypatch):
-        """Poison the lock-step collective primitives: the megastep
-        path is single-device dispatches + host merge only."""
+        """Spy on ``_launch``: every megastep takes arrays committed to
+        one device — single-device dispatches + host merge only."""
+        import jax
+
         import repro.core.engine as engine_mod
+        seen = []
+        real = engine_mod._launch
 
-        def poison(*a, **k):
-            raise AssertionError(
-                "async megastep entered a cross-shard collective")
+        def spy(step, backend, *args):
+            seen.append((step, {d for a in args
+                                if isinstance(a, jax.Array)
+                                for d in a.devices()}))
+            return real(step, backend, *args)
 
-        monkeypatch.setattr(engine_mod, "_part_desc_step", poison)
-        monkeypatch.setattr(engine_mod, "_part_chunk_step", poison)
+        monkeypatch.setattr(engine_mod, "_launch", spy)
         g = pl_graph(n=70, seed=13)
         eng = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                           partition=True, schedule="async",
+                           partition=True,
                            max_windows_per_dispatch=cap)
         got = eng.run(g, max_items=120,
                       part=skewed_partition(g, 4))
         np.testing.assert_array_equal(got, census_batagelj_mrvar(g))
+        assert len(seen) == eng.stats.dispatches_total
+        assert all(step is engine_mod._desc_megastep and len(devs) == 1
+                   for step, devs in seen)

@@ -385,10 +385,13 @@ class TestShardCountInvariance:
     def test_compile_once_across_steps(self):
         g = pl_graph(n=90, seed=21)
         engine = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                              partition=True, schedule="lockstep")
-        engine.run(g, max_items=64)        # many lock-step windows
+                              partition=True)
+        engine.run(g, max_items=64)        # many windows per shard
         assert engine.stats.chunks >= 4
-        assert engine.stats.step_compiles <= 1
+        # one compile per device at most, never one per window
+        assert engine.stats.step_compiles <= 4
+        engine.run(g, max_items=64)
+        assert engine.stats.step_compiles == 0
 
     def test_graph_bytes_reported(self):
         g = pl_graph(n=300, deg=6, seed=3)
@@ -657,20 +660,12 @@ class TestPhysicalStats:
     def test_partitioned_upload_is_private_window(self):
         from repro.core.planner import num_desc_anchors
         g = pl_graph(n=80, seed=31)
-        part = CensusEngine(mesh=default_mesh(4), backend="jnp",
-                            partition=True, emit="device",
-                            schedule="lockstep")
-        part.run(g, max_items=400)
-        st = part.stats
-        per_dev = st.chunk_shape // 4    # lock-step records global lanes
-        assert st.plan_upload_bytes == 4 * (
-            1 + 3 * st.desc_shape + num_desc_anchors(per_dev))
-        # async stats record the per-dispatch (single-device) window:
-        # same per-device upload unit, chunk_shape already per-device
+        # the stats record the per-dispatch (single-device) window:
+        # chunk_shape is per device, and so is the upload unit
         part = CensusEngine(mesh=default_mesh(4), backend="jnp",
                             partition=True, emit="device")
         part.run(g, max_items=400)
         st = part.stats
-        assert st.schedule == "async"
+        assert st.partitioned
         assert st.plan_upload_bytes == 4 * (
             1 + 3 * st.desc_shape + num_desc_anchors(st.chunk_shape))

@@ -13,8 +13,7 @@ Central properties:
   duplicated, or moved across pair-shard boundaries.
 * **Mesh invariance** — censuses are bit-identical across 2D mesh
   shapes, the 1D path, and the Batagelj–Mrvar reference, for both
-  orients, both emit modes and both schedules, full runs and
-  incremental sessions.
+  orients and both emit modes, full runs and incremental sessions.
 * **Halo sharding** — the per-device resident adjacency entries (the
   halo the decomposition targets) shrink vs 1D at the same device count.
 """
@@ -28,8 +27,8 @@ import pytest
 from repro.core import (
     CensusEngine, apply_delta, census_batagelj_mrvar, default_mesh,
     extract_shard, from_edges, lpt_assign, lpt_assign_heap, pair_space,
-    partition_graph, partition_graph_2d, scale_free_digraph, shard_report,
-    triad_census_graph, vertex_slices)
+    paper_workload, partition_graph, partition_graph_2d,
+    scale_free_digraph, shard_report, triad_census_graph, vertex_slices)
 from repro.core.partition import GraphPartition2D, slice_pair_terms
 from repro.core.plan_stream import ShardStreamPipeline
 from repro.core.planner import (
@@ -40,7 +39,7 @@ from repro.core.planner import (
 @pytest.fixture(scope="module", autouse=True)
 def _shed_compile_cache():
     """Drop compiled executables around this module.  The mesh-shape ×
-    orient × emit × schedule sweeps below compile many distinct
+    orient × emit sweeps below compile many distinct
     multi-device programs; stacked on the rest of the suite's cache in
     one process, the per-process executable population grows without
     bound.  Clearing before and after keeps
@@ -311,6 +310,22 @@ class TestPartition2D:
         assert max(p2.stats.shard_entries) < max(p1.stats.shard_entries)
         assert p2.stats.entry_replication < p1.stats.entry_replication
 
+    @pytest.mark.parametrize("mesh_shape,cut", [((4, 2), 1.5),
+                                                ((2, 4), 2.0)],
+                             ids=["4x2", "2x4"])
+    def test_halo_cut_on_power_law(self, mesh_shape, cut):
+        """On the power-law patents workload at 8 devices, the largest
+        per-device halo is at least ``cut`` times smaller than 1D's, and
+        at (4, 2) the largest shard is no larger in bytes."""
+        g = paper_workload("patents", n=20_000, avg_degree=8.0, seed=0)
+        sp = pair_space(g)
+        p1 = partition_graph(space=sp, num_shards=8)
+        p2 = partition_graph_2d(space=sp, mesh_shape=mesh_shape)
+        assert max(p1.stats.shard_entries) >= \
+            cut * max(p2.stats.shard_entries)
+        if mesh_shape == (4, 2):
+            assert p2.stats.max_shard_bytes <= p1.stats.max_shard_bytes
+
     def test_stats_report_2d(self):
         part = partition_graph_2d(pl_graph(seed=8), mesh_shape=(2, 2))
         rep = shard_report(part)
@@ -355,14 +370,12 @@ class TestMeshInvariance:
                                emit=emit, partition_2d=(2, 2))
         np.testing.assert_array_equal(c, ref)
 
-    @pytest.mark.parametrize("schedule", ["async", "lockstep"])
-    def test_schedules_and_streaming(self, schedule):
-        """The async/lock-step/megastep machinery runs unmodified over
-        the 2D tile queue set."""
+    def test_schedules_and_streaming(self):
+        """The per-shard stream and megastep machinery runs unmodified
+        over the 2D tile queue set."""
         g = pl_graph(n=110, deg=5, seed=23)
         ref = census_batagelj_mrvar(g)
-        eng = CensusEngine(mesh=default_mesh(8), partition_2d=(4, 2),
-                           schedule=schedule)
+        eng = CensusEngine(mesh=default_mesh(8), partition_2d=(4, 2))
         c = eng.run(g, max_items=500)
         np.testing.assert_array_equal(c, ref)
         assert eng.stats.partition_shape == (4, 2)

@@ -121,7 +121,6 @@ class TestSpan:
 PATHS = {
     "stream-device": {},
     "stream-host": {"emit": "host"},
-    "lockstep": {"partition": True, "schedule": "lockstep"},
     "async-device": {"partition": True},
     "async-host": {"partition": True, "emit": "host"},
 }

@@ -120,8 +120,8 @@ def test_runtime_error_is_still_retried(monkeypatch):
     g = _graph()
     mesh = default_mesh(2)
     want = CensusEngine().run(g, max_items=512)
-    flaky = _FailsOnceAtRuntime(eng._desc_megastep(mesh))
-    monkeypatch.setattr(eng, "_desc_megastep", lambda mesh=None: flaky)
+    flaky = _FailsOnceAtRuntime(eng._desc_megastep)
+    monkeypatch.setattr(eng, "_desc_megastep", flaky)
     engine = CensusEngine(mesh, partition=True, retry_backoff=0.0)
     got = engine.run(g, max_items=1024)
     assert np.array_equal(got, want)

@@ -6,7 +6,7 @@ import pytest
 
 from repro.core import (
     SECURITY_PATTERN_INDICES, SECURITY_PATTERNS, TRIAD_NAMES, TriadMonitor,
-    build_plan, from_edges, triad_census)
+    build_plan, from_edges, monitor_stream, triad_census)
 
 
 def direct_census(src, dst, n, lo, hi, backend="jnp", orient="none"):
@@ -96,6 +96,51 @@ class TestWindowParity:
         mon.observe(src, dst)
         slid = mon.window_stats[1:]
         assert slid and all(s.items < s.full_items for s in slid)
+
+    @pytest.mark.parametrize("backend", ["jnp", "pallas-fused"])
+    def test_incremental_halves_items(self, backend):
+        """Sliding a service-backbone stream at a 10% stride: the
+        incremental windows equal full recomputes, count at least 2x
+        fewer items, and compile the session step at most once."""
+        rng = np.random.default_rng(0)
+        window = 1500
+        src, dst, n = monitor_stream(rng, 40, 1500, 300, 5 * window)
+        mons = {}
+        for incremental in (True, False):
+            mons[incremental] = TriadMonitor(
+                n, window=window, stride=window // 10, history=5,
+                backend=backend, incremental=incremental, max_items=2048)
+            mons[incremental].observe(src, dst)
+        np.testing.assert_array_equal(mons[True].censuses,
+                                      mons[False].censuses)
+        slid = mons[True].window_stats[1:]
+        items = sum(s.items for s in slid)
+        full_items = sum(s.full_items for s in slid)
+        assert full_items >= 2 * items > 0
+        assert sum(s.step_compiles for s in mons[True].window_stats) <= 1
+
+    @pytest.mark.parametrize("orient", ["none", "degree"])
+    def test_indexed_monitor_matches_rebuild(self, orient):
+        """Warm slides through the persistent pair-space index give the
+        censuses and post-prune item totals of the per-window rebuild
+        (``index=False``) on a backbone-dominated stream."""
+        rng = np.random.default_rng(0)
+        window, stride = 4000, 200
+        src, dst, n = monitor_stream(rng, 200, 500, 1500,
+                                     window + 8 * stride, eph_every=50)
+        mons = {}
+        for index in (True, False):
+            mons[index] = TriadMonitor(
+                n, window=window, stride=stride, history=5,
+                orient=orient, max_items=2048, index=index)
+            mons[index].observe(src, dst)
+        np.testing.assert_array_equal(mons[True].censuses,
+                                      mons[False].censuses)
+        slid = {ix: mons[ix].window_stats[1:] for ix in mons}
+        assert len(slid[True]) == 8
+        assert all(s.indexed for s in slid[True])
+        assert [s.full_items for s in slid[True]] == \
+            [s.full_items for s in slid[False]]
 
 
 # ------------------------------------------------------------ observe input

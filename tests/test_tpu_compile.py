@@ -3,8 +3,9 @@ attached.
 
 The TPU compiler ships with jaxlib, so these tests lower and compile the
 steps of the main (``jnp``) path, the repaired Pallas histogram kernel
-and the partitioned collective step at the shapes ``chip_smoke.py`` runs
-— the cit-Patents-scale batch graph, 4M-item chunks — and check that
+and the per-chip megastep of partitioned runs at the shapes
+``chip_smoke.py`` runs — the cit-Patents-scale batch graph, 4M-item
+chunks — and check that
 each fits one chip's 16 GB.  Nothing runs: these tests only show what
 the chip's compiler accepts.  They also pin the reason the fused kernel
 is refused on a TPU.
@@ -19,9 +20,7 @@ import re
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import engine as eng
@@ -99,7 +98,7 @@ def _fits_one_chip(compiled) -> None:
 
 def test_chunk_step_compiles(graph, i32):
     """Host emission: packed items through ``census_partials``."""
-    compiled = eng._chunk_step_donated.lower(
+    compiled = eng._chunk_step.lower(
         *graph, i32(CHUNK), i32(CHUNK), None, SEARCH_ITERS,
         "jnp").compile()
     _fits_one_chip(compiled)
@@ -169,7 +168,7 @@ def test_desc_step_gathers_each_pair_field_once(topo):
 
 def test_megastep_compiles(graph, i32):
     """Async partitioned runs: K stacked windows scanned per dispatch."""
-    compiled = eng._desc_megastep_donated.lower(
+    compiled = eng._desc_megastep.lower(
         *graph, i32(K, DESC_WORDS), i32(CHUNK), SEARCH_ITERS,
         DESC_SEARCH_ITERS, "jnp", "degree", True).compile()
     _fits_one_chip(compiled)
@@ -186,31 +185,10 @@ def test_pallas_backend_chunk_step_compiles(graph, i32, monkeypatch):
     histogram kernel in it (the wrapper would pick interpret mode from
     this host's CPU backend)."""
     monkeypatch.setattr(ops, "_interpret_default", lambda: False)
-    compiled = eng._chunk_step_donated.lower(
+    compiled = eng._chunk_step.lower(
         *graph, i32(CHUNK), i32(CHUNK), None, SEARCH_ITERS,
         "pallas").compile()
     assert "tpu_custom_call" in compiled.as_text()
-    _fits_one_chip(compiled)
-
-
-def test_partitioned_desc_step_compiles_on_four_chips(topo):
-    """The lock-step partitioned step on the 2x2 host: each chip holds
-    one shard (bounded here by the whole graph) and one psum closes."""
-    mesh = Mesh(np.asarray(topo.devices[:4]), ("d",))
-    sharded = NamedSharding(mesh, P("d"))
-    rep = NamedSharding(mesh, P())
-
-    def per_chip(length):
-        return jax.ShapeDtypeStruct((4, length), jnp.int32,
-                                    sharding=sharded)
-
-    compiled = eng._part_desc_step.lower(
-        per_chip(N_INDPTR), per_chip(N_PACKED), per_chip(N_PAIRS),
-        per_chip(N_PAIRS), per_chip(N_PAIRS), per_chip(DESC_WORDS),
-        jax.ShapeDtypeStruct((CHUNK,), jnp.int32, sharding=rep),
-        mesh, SEARCH_ITERS, DESC_SEARCH_ITERS, "jnp", "degree",
-        True).compile()
-    assert "all-reduce" in compiled.as_text()
     _fits_one_chip(compiled)
 
 
@@ -235,6 +213,6 @@ def test_fused_kernel_still_refused_by_mosaic(i32, monkeypatch):
     assert "pallas-fused" in TPU_REFUSED
     monkeypatch.setattr(ops, "_interpret_default", lambda: False)
     with pytest.raises(NotImplementedError, match="Only 2D gather"):
-        eng._chunk_step_donated.lower(
+        eng._chunk_step.lower(
             i32(1025), i32(4096), i32(2048), i32(2048), i32(2048),
             i32(8192), i32(8192), None, 8, "pallas-fused").compile()
